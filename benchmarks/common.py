@@ -79,8 +79,8 @@ def trained_proxy(name: str, steps: int = 200, seed: int = 0):
 
 
 def timeit_p50(fn, *args, warmup=1, repeats=5):
-    """Single timing discipline for every bench lane (and the same one the
-    kernel autotuner uses — `repro.kernels.autotune.measure_candidate`):
+    """Single timing discipline for every bench lane (and for the kernel
+    autotuner's measurements, which `benchmarks/kernel_bench.py` makes):
     `warmup` discarded calls to absorb compilation/tracing, then the p50 of
     `repeats` wall-clock measurements, each fenced by `jax.block_until_ready`
     so async dispatch cannot hide device time. Returns (us_per_call, out).

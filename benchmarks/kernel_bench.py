@@ -9,11 +9,13 @@ Two lanes (benchmarks/run.py --backend, DESIGN.md §11):
               which under the interpreter are exactly the `_pick_blocks`
               heuristic;
   compiled  — real wall-clock of compiled code on whatever the host offers:
-              the compiled Pallas kernels on TPU (where the autotuner measures
-              its candidate grid on first sight of each shape and the winner
-              can only match or beat the heuristic — the heuristic is in the
-              grid), the XLA-compiled gather contraction elsewhere (the actual
-              CPU serving dispatch).
+              the compiled Pallas kernels on TPU, the XLA-compiled gather
+              contraction elsewhere (the actual CPU serving dispatch). On TPU
+              this lane is the autotuner's measuring caller: before timing a
+              shape it times the candidate grid (the heuristic is in it, so
+              the winner can only match or beat it) outside any trace and
+              stores the winner in the autotune cache, which the serving
+              wrappers then read while they trace (DESIGN.md §11).
 
 Every row also carries the v5e roofline byte model (weight-stream bytes per
 GEMV for bf16 vs packed sub-byte codes — the quantity the decode speedup
@@ -29,12 +31,25 @@ import numpy as np
 from benchmarks.common import emit, timeit_p50
 from repro.core.lut import pack4
 from repro.kernels import autotune
-from repro.kernels.ops import lut_gemm
+from repro.kernels.lut_matmul import lut_matmul_f32
+from repro.kernels.ops import lut_gemm, pad_codebook, pad_for_kernel
 from repro.kernels.ref import lut_matmul_f32_ref
 
 HBM_BW = 819e9
 
 SHAPES = ((1, 4096, 4096), (8, 4096, 11008), (128, 2048, 2048))
+
+
+def _measure_fn(x, packed, cb):
+    """measure(bm, bn, bk) -> seconds of the compiled `lut_gemm` kernel at
+    those blocks, timed eagerly (never inside a trace)."""
+    def measure(bm: int, bn: int, bk: int) -> float:
+        xp, cp, _ = pad_for_kernel(x, packed, bm, bk, bn, 4)
+        us, _ = timeit_p50(functools.partial(
+            lut_matmul_f32, xp, cp, pad_codebook(cb), bm=bm, bn=bn, bk=bk,
+            interpret=False, nbits=4))
+        return us * 1e-6
+    return measure
 
 
 def run(backend: str = "interpret") -> dict:
@@ -67,16 +82,17 @@ def run(backend: str = "interpret") -> dict:
                 f"{jax.default_backend()!r}): Pallas TPU kernels cannot "
                 f"compile, timing the XLA gather contraction instead")
         else:
-            # lut_gemm consults the autotuner: cached winner, measured on
-            # first sight (TPU compiled), the heuristic under the interpreter
+            # the blocks lut_gemm resolves while it traces: the cached
+            # winner, which this lane measures first on TPU, else (and
+            # always under the interpreter) the heuristic
+            measure = None if interpret else _measure_fn(x, packed, cb)
+            tuned = list(autotune.pick_blocks(
+                m, k, n, nbits=4, variant="lut_f32", interpret=interpret,
+                measure=measure))
             us_lut, _ = timeit_p50(
                 functools.partial(lut_gemm, x, packed, cb,
                                   interpret=interpret))
             kernel = "pallas-interpret" if interpret else "pallas"
-            tuned = list(autotune.pick_blocks(
-                m, k, n, nbits=4,
-                variant="lut_fused_gemv" if m < 128 else "lut_f32",
-                interpret=interpret))
 
         bytes_bf16 = k * n * 2
         bytes_int4 = k * n // 2 + 16 * 4

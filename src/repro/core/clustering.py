@@ -237,10 +237,19 @@ def assign(w: jax.Array, state: ClusterState) -> jax.Array:
     return jnp.argmin(d, axis=-1).astype(jnp.int32)
 
 
+def lookup(codes: jax.Array, table: jax.Array) -> jax.Array:
+    """table[codes] for a K_MAX-entry table, as a select-sum over the
+    entries: exactly one term is nonzero, so it equals the gather, and it
+    lowers to fused elementwise work where a TPU gather of a tiny table
+    over millions of indices is slow."""
+    hit = codes[..., None] == jnp.arange(K_MAX, dtype=codes.dtype)
+    return jnp.sum(jnp.where(hit, table, jnp.zeros((), table.dtype)), axis=-1)
+
+
 @jax.jit
 def dequant(codes: jax.Array, state: ClusterState) -> jax.Array:
     safe = jnp.where(state.active, state.centroids, 0.0)
-    return safe[codes]
+    return lookup(codes, safe)
 
 
 # --- objective (paper Eq. 4, normalized) -------------------------------------
@@ -272,9 +281,12 @@ def refresh(w: jax.Array, codes: jax.Array, state: ClusterState, h: jax.Array) -
     """
     flat_w = w.reshape(-1)
     flat_h = h.reshape(-1)
-    flat_c = codes.reshape(-1)
-    mass = jnp.zeros((K_MAX,), jnp.float32).at[flat_c].add(flat_h)
-    wsum = jnp.zeros((K_MAX,), jnp.float32).at[flat_c].add(flat_h * flat_w)
+    hit = codes.reshape(-1)[:, None] == jnp.arange(K_MAX, dtype=codes.dtype)
+    # per-cluster sums as a masked reduction, not a scatter-add: on TPU a
+    # scatter of millions of updates into K_MAX bins serializes on the
+    # colliding indices (~0.1 s per call at a 1536x8960 layer)
+    mass = jnp.sum(jnp.where(hit, flat_h[:, None], 0.0), axis=0)
+    wsum = jnp.sum(jnp.where(hit, (flat_h * flat_w)[:, None], 0.0), axis=0)
     new = jnp.where(mass > 0, wsum / jnp.maximum(mass, 1e-30), state.centroids)
     new = jnp.where(state.active, new, _INACTIVE)
     return ClusterState(new, state.active, mass)

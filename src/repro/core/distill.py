@@ -101,7 +101,7 @@ def lcd_step(
     # shrink toward the teacher, but we defensively re-sort (cheap, K_MAX=32).
     order = jnp.argsort(state2.centroids)
     state2 = C.ClusterState(state2.centroids[order], state2.active[order], state2.counts[order])
-    codes2 = jnp.argsort(order)[codes2]
+    codes2 = C.lookup(codes2, jnp.argsort(order).astype(codes2.dtype))
 
     # Distortion against the *teacher* (the quantity Eq. 4 bounds).
     j = C.objective(w_teacher, codes2, state2, h)
@@ -174,12 +174,12 @@ def distill_layer(
     hist_tr: List[float] = []
     spec_events: List[Tuple[int, str]] = []
 
-    best = None  # (J, k, codes, state) — lowest-k solution within tolerance
     steps_since_merge = 0
     spec_round = 0
     eps_scale = 2.0
     j_prev = np.inf
 
+    h_sum = jnp.sum(h)           # loop-invariant; one reduction, not one per step
     step = 0
     while step < cfg.max_steps:
         codes, state, j, merged = lcd_step(
@@ -190,7 +190,7 @@ def distill_layer(
         kf = C.num_active(state)
         hist_j.append(jf)
         hist_k.append(kf)
-        hist_tr.append(float(jnp.sum(h) * jf))  # H-trace-scaled distortion monitor
+        hist_tr.append(float(h_sum * jf))  # H-trace-scaled distortion monitor
         step += 1
 
         if bool(merged):
@@ -198,17 +198,13 @@ def distill_layer(
         else:
             steps_since_merge += 1
 
-        # track the best (lowest-k, then lowest-J) solution seen
-        if best is None or (kf, jf) < (best[1], best[0] * cfg.spec_tolerance):
-            best = (jf, kf, np.asarray(codes), state)
-
         # --- speculative search trigger: stagnation + non-monotone trace ----
         stagnated = steps_since_merge >= cfg.spec_patience
         non_monotone = jf > j_prev - 1e-12
         j_prev = jf
         if speculative and stagnated and non_monotone and spec_round < cfg.spec_rounds:
             spec_round += 1
-            snap = (np.asarray(codes), state, jf, kf)
+            snap = (codes, state, jf, kf)   # device arrays are immutable
             try:
                 state_s, codes_s = _init_from_dbci(w_teacher, cfg, eps_scale=eps_scale)
             except ValueError:
@@ -232,7 +228,7 @@ def distill_layer(
                 eps_scale = 2.0
                 steps_since_merge = 0
             else:
-                codes, state = jnp.asarray(snap[0]), snap[1]
+                codes, state = snap[0], snap[1]
                 spec_events.append((step, f"reverted (cand k={ks} J={js:.3e}, eps x{eps_scale})"))
                 eps_scale = 1.5  # paper: back off 2*eps -> 1.5*eps
         elif stagnated and not speculative:

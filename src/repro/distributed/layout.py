@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.distributed.hlo_cost import analyze_text
+from repro.launch.mesh import auto_mesh
 from repro.distributed.sharding import (auto_shard, named_sharding,
                                         parse_names, use_rules)
 
@@ -121,7 +122,7 @@ def choose_layout(model, params, ecfg, *,
     report: Dict[str, Any] = {"devices": n, "candidates": {}}
     best: Optional[Tuple[float, Any, str]] = None
     for data, mp in candidate_layouts(n):
-        mesh = jax.make_mesh((data, mp), ("data", "model"), devices=devices)
+        mesh = auto_mesh((data, mp), ("data", "model"), devices=devices)
         row = score_layout(model, params, ecfg, mesh)
         key = f"{data}x{mp}"
         report["candidates"][key] = {

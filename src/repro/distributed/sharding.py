@@ -112,6 +112,17 @@ def current_rules() -> Optional[ShardingRules]:
     return _rules_ctx.get()
 
 
+def kernel_mesh() -> Optional[Mesh]:
+    """The installed rules' mesh when it spans more than one device, else
+    None. A Pallas TPU kernel is one custom call that GSPMD cannot
+    partition, so on such a mesh the kernel wrappers run it per device
+    under `shard_map` (kernels/ops.py, kernels/paged_attention.py)."""
+    sr = current_rules()
+    if sr is None or sr.mesh.size == 1:
+        return None
+    return sr.mesh
+
+
 def logical_to_spec(shape: Sequence[int], names: Sequence[Optional[str]],
                     sr: Optional[ShardingRules] = None) -> P:
     """Build a PartitionSpec for `shape` from logical dim names, dropping any

@@ -7,16 +7,18 @@
   smooth_quant.py — standalone smooth+quantize input transform (Eq. 11);
                     kept for calibration tooling — the serving path runs the
                     transform inside the fused GEMM instead
-  paged_attention.py — fused dequantizing paged attention over the int8 KV
-                    block pool (DESIGN.md §9): int8 tiles + scales dequantize
-                    in VMEM, the full-precision cache never exists in HBM
+  paged_attention.py — paged attention reading the float or int8 block
+                    pools in place (DESIGN.md §9, §15): int8 tiles + scales
+                    dequantize in VMEM, the full-precision cache never exists
+                    in HBM; per device under shard_map on a mesh (§14)
   ops.py          — padded/blocked jit wrappers, variant selection, CPU
-                    fallbacks, and the lut_serving dispatch context
-  autotune.py     — measured block-shape autotuner (DESIGN.md §11): every
-                    entry point's (bm, bn, bk)/(bq, bk) tile shapes come from
-                    its persistent cache, measured per (shape, nbits, backend)
-                    on compiled backends, exactly the _pick_blocks heuristic
-                    under the interpreter
+                    fallbacks, per-device shard_map on a mesh (§14), and the
+                    lut_serving dispatch context
+  autotune.py     — block-shape autotuner (DESIGN.md §11): every entry
+                    point's (bm, bn, bk)/(bq, bk) tile shapes come from its
+                    persistent cache, else exactly the _pick_blocks
+                    heuristic; the traced wrappers never measure (the
+                    compiled kernel bench fills the cache on TPU)
   ref.py          — pure-jnp oracles (asserted in tests/test_kernels.py and
                     tests/test_paged_kv.py)
 """

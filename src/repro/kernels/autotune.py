@@ -20,11 +20,11 @@ This module is the single place block shapes come from:
              heuristic choice, filtered by the VMEM working-set budget the
              heuristic enforces (`vmem_bytes` ≤ VMEM_BUDGET) — the tuner can
              never propose a spec the kernel could not run.
-  measure    warmup + p50-of-repeats wall-clock via `jax.block_until_ready`
-             (benchmarks/common.py `timeit_p50` uses the same discipline, so
-             bench timings and tuner timings agree on methodology).
+  measure    a caller-supplied `measure(*blocks) -> seconds`, run outside
+             any trace (benchmarks/common.py `timeit_p50` is the discipline:
+             warmup, then p50 of repeats fenced by `block_until_ready`).
   cache      in-process dict backed by a persistent JSON store
-             (`~/.cache/repro/autotune.json`, override with
+             (`<checkout>/.autotune.json`, override with
              $REPRO_AUTOTUNE_CACHE; versioned schema, corrupt-file tolerant).
              A cache hit NEVER re-measures (asserted in tests/test_autotune).
 
@@ -33,17 +33,25 @@ and on a cache miss with measurement unavailable or disabled
 ($REPRO_AUTOTUNE=0), `pick_blocks` returns exactly `heuristic_blocks`'s
 choice — CPU CI and the interpret benches behave precisely as before the
 tuner existed.
+
+The kernel wrappers never pass a `measure` fn: they run while a `jax.jit`
+is tracing, where a timed call is staged into the trace instead of running,
+so the traced serving path always resolves to a cache hit or the
+heuristic. Measuring is for a caller outside any trace: the compiled lane
+of `benchmarks/kernel_bench.py` on TPU measures the LUT GEMM's grid and
+fills the cache. Nothing measures the attention kernels, so
+`pick_flash_blocks` and `pick_paged_pad` serve a cache hit or their
+heuristic.
 """
 from __future__ import annotations
 
 import json
 import os
-import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 
-from repro.utils import round_up
+from repro.utils import REPO_ROOT, logger, round_up
 
 # ---------------------------------------------------------------------------
 # Heuristic (the seed's _pick_blocks — kept verbatim as the deterministic
@@ -130,33 +138,10 @@ def flash_heuristic(sq: int, sk: int) -> Tuple[int, int]:
     return min(256, sq), min(512, sk)
 
 
-def flash_candidates(sq: int, sk: int) -> List[Tuple[int, int]]:
-    """(bq, bk) pairs that divide the (sq, sk) geometry exactly — the flash
-    kernel requires whole blocks (no padding path)."""
-    heur = flash_heuristic(sq, sk)
-    bqs = [b for b in (64, 128, 256, 512) if b <= sq and sq % b == 0]
-    bks = [b for b in (128, 256, 512, 1024) if b <= sk and sk % b == 0]
-    out = [heur]
-    for bq in bqs or [sq]:
-        for bk in bks or [sk]:
-            if (bq, bk) != heur and (bq, bk) not in out:
-                out.append((bq, bk))
-    return out
-
-
 def paged_heuristic() -> Tuple[int]:
     """Lane-alignment multiple for the gathered KV length (the seed padded
     to 128 lanes unconditionally)."""
     return (128,)
-
-
-def paged_candidates(l: int) -> List[Tuple[int]]:
-    """KV-length padding multiples: wider lanes trade pad-FLOPs for fewer
-    ragged edges; only worth measuring when L exceeds one lane tile."""
-    out = [paged_heuristic()]
-    if l > 128:
-        out.append((256,))
-    return out
 
 
 # ---------------------------------------------------------------------------
@@ -190,10 +175,9 @@ def normalize_key(m: int, k: int, n: int, nbits: int, variant: str,
 # ---------------------------------------------------------------------------
 
 def cache_path() -> str:
-    return os.environ.get(
-        _ENV_CACHE,
-        os.path.join(os.path.expanduser("~"), ".cache", "repro",
-                     "autotune.json"))
+    """$REPRO_AUTOTUNE_CACHE when set, else one fixed file in the checkout
+    (git-ignored) — the compile cache's rule (utils.enable_compile_cache)."""
+    return os.environ.get(_ENV_CACHE, str(REPO_ROOT / ".autotune.json"))
 
 
 class AutotuneCache:
@@ -275,33 +259,26 @@ def tuning_enabled() -> bool:
     return os.environ.get(_ENV_ENABLE, "1") != "0"
 
 
-def measure_candidate(fn: Callable[[], object], warmup: int = 1,
-                      repeats: int = 5) -> float:
-    """p50 wall-clock seconds of `fn` (which must return a JAX value), after
-    `warmup` discarded calls — same discipline as benchmarks/common.timeit_p50
-    but dependency-free so the kernels layer never imports the bench layer."""
-    for _ in range(max(warmup, 0)):
-        jax.block_until_ready(fn())
-    ts = []
-    for _ in range(max(repeats, 1)):
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn())
-        ts.append(time.perf_counter() - t0)
-    ts.sort()
-    return ts[len(ts) // 2]
-
-
 def _tune(key: str, candidates, measure, cache: AutotuneCache):
-    best, best_t = None, float("inf")
+    """Measure every candidate and store the fastest. A candidate the
+    backend refuses loses, with its error logged; when every candidate is
+    refused the first refusal is raised — the grid is VMEM-filtered, so
+    that is a kernel fault, never a reason to serve the heuristic."""
+    best, best_t, refused = None, float("inf"), []
     for cand in candidates:
         try:
             t = measure(*cand)
-        except Exception:             # a candidate the backend rejects loses
+        except Exception as e:        # noqa: BLE001 — re-raised below
+            logger.warning("autotune %s: candidate %s refused: %s",
+                           key, cand, e)
+            refused.append(e)
             continue
         if t < best_t:
             best, best_t = cand, t
-    if best is None:                  # every candidate failed: heuristic wins
-        return None
+    if best is None:
+        raise RuntimeError(
+            f"autotune {key}: all {len(refused)} candidates refused"
+        ) from (refused[0] if refused else None)
     cache.put(key, best, best_t * 1e6)
     return tuple(best)
 
@@ -320,8 +297,8 @@ def pick_blocks(m: int, k: int, n: int, *, nbits: int = 4,
       3. miss + interpret / disabled / no
          measure fn                        -> exactly `heuristic_blocks`.
 
-    `measure(bm, bn, bk) -> seconds` is injected by the caller (the kernel
-    wrappers build one only on a compiled backend; tests inject counters).
+    `measure(bm, bn, bk) -> seconds` is injected by a caller outside any
+    trace (benchmarks/kernel_bench.py on TPU; tests inject counters).
     Shape args must be the post-group-padding problem the kernel will run.
     """
     backend = "interpret" if interpret else jax.default_backend()
@@ -332,41 +309,32 @@ def pick_blocks(m: int, k: int, n: int, *, nbits: int = 4,
         return hit                    # cache hit: never re-measure
     if interpret or measure is None or not tuning_enabled():
         return heuristic_blocks(m, k, n)
-    won = _tune(key, candidate_blocks(m, k, n, nbits, variant, n_ops),
-                measure, cache)
-    return won if won is not None else heuristic_blocks(m, k, n)
+    return _tune(key, candidate_blocks(m, k, n, nbits, variant, n_ops),
+                 measure, cache)
 
 
 def pick_flash_blocks(sq: int, sk: int, d: int, *, interpret: bool = True,
-                      measure: Optional[Callable[..., float]] = None,
                       cache: Optional[AutotuneCache] = None
                       ) -> Tuple[int, int]:
-    """(bq, bk) for the flash-attention kernel; same resolution order as
-    `pick_blocks`. Key geometry: (m=sq, k=sk, n=d), nbits=0 (no packing)."""
-    backend = "interpret" if interpret else jax.default_backend()
-    cache = cache or get_cache()
-    key = normalize_key(sq, sk, d, 0, "flash", backend)
-    hit = cache.get(key)
-    if hit is not None:
-        return hit
-    if interpret or measure is None or not tuning_enabled():
+    """(bq, bk) for the flash-attention kernel: a cached winner on a compiled
+    backend, else `flash_heuristic`. Nothing measures the attention kernels
+    yet. Key geometry: (m=sq, k=sk, n=d), nbits=0 (no packing)."""
+    if interpret:
         return flash_heuristic(sq, sk)
-    won = _tune(key, flash_candidates(sq, sk), measure, cache)
-    return won if won is not None else flash_heuristic(sq, sk)
+    cache = cache or get_cache()
+    hit = cache.get(normalize_key(sq, sk, d, 0, "flash",
+                                  jax.default_backend()))
+    return hit if hit is not None else flash_heuristic(sq, sk)
 
 
 def pick_paged_pad(gt: int, l: int, d: int, *, interpret: bool = True,
-                   measure: Optional[Callable[..., float]] = None,
                    cache: Optional[AutotuneCache] = None) -> int:
     """Lane-padding multiple for the paged dequant-attention kernel's gathered
-    KV length; same resolution order. Key geometry: (m=gt, k=l, n=d)."""
-    backend = "interpret" if interpret else jax.default_backend()
-    cache = cache or get_cache()
-    key = normalize_key(gt, l, d, 8, "paged", backend)
-    hit = cache.get(key)
-    if hit is not None:
-        return hit[0]
-    if interpret or measure is None or not tuning_enabled():
+    KV length: a cached winner on a compiled backend, else
+    `paged_heuristic`. Key geometry: (m=gt, k=l, n=d)."""
+    if interpret:
         return paged_heuristic()[0]
-    won = _tune(key, paged_candidates(l), measure, cache)
-    return won[0] if won is not None else paged_heuristic()[0]
+    cache = cache or get_cache()
+    hit = cache.get(normalize_key(gt, l, d, 8, "paged",
+                                  jax.default_backend()))
+    return hit[0] if hit is not None else paged_heuristic()[0]

@@ -42,24 +42,6 @@ from repro.kernels import autotune
 NEG_INF = -1e30
 
 
-def _flash_measure_fn(bh: int, sq: int, sk: int, d: int, dtype, kw: dict):
-    """measure(bq, bk) -> seconds on synthetic (bh, s, d) operands — built
-    only on a compiled backend (DESIGN.md §11); the real q/k/v are tracers
-    when the wrapper is being jit-traced, and timing depends on shapes, not
-    values."""
-    rng = np.random.default_rng(0)
-    q = jnp.asarray(rng.normal(size=(bh, sq, d)), dtype)
-    k = jnp.asarray(rng.normal(size=(bh, sk, d)), dtype)
-    v = jnp.asarray(rng.normal(size=(bh, sk, d)), dtype)
-
-    def measure(bq: int, bk: int) -> float:
-        return autotune.measure_candidate(
-            lambda: flash_attention(q, k, v, bq=bq, bk=bk, interpret=False,
-                                    **kw))
-
-    return measure
-
-
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, bq: int, bk: int, sk: int,
                   scale: float, causal: bool, window: int, softcap: float,
                   q_offset: int, k_len: int):
@@ -71,10 +53,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, bq: int, bk: int, sk: int,
 
     def body(j, carry):
         acc, m, l = carry
-        k = pl.load(k_ref, (0, pl.dslice(j * bk, bk), slice(None))
-                    ).astype(jnp.float32)                 # (bk, d)
-        v = pl.load(v_ref, (0, pl.dslice(j * bk, bk), slice(None))
-                    ).astype(jnp.float32)
+        k = k_ref[0, pl.ds(j * bk, bk), :].astype(jnp.float32)  # (bk, d)
+        v = v_ref[0, pl.ds(j * bk, bk), :].astype(jnp.float32)
         s = q @ k.T                                       # (bq, bk)
         if softcap > 0:
             s = softcap * jnp.tanh(s / softcap)
@@ -110,7 +90,7 @@ def flash_attention(
     k: jax.Array,          # (BH, Sk, D)
     v: jax.Array,          # (BH, Sk, D)
     *,
-    bq: int = None,        # None -> autotuned (DESIGN.md §11)
+    bq: int = None,        # None -> autotune cache/heuristic (DESIGN.md §11)
     bk: int = None,
     causal: bool = True,
     window: int = 0,
@@ -122,14 +102,7 @@ def flash_attention(
     bh, sq, d = q.shape
     sk = k.shape[1]
     if bq is None or bk is None:
-        measure = None
-        if not interpret and jax.default_backend() == "tpu":
-            measure = _flash_measure_fn(
-                bh, sq, sk, d, q.dtype,
-                dict(causal=causal, window=window, softcap=softcap,
-                     q_offset=q_offset, k_len=k_len))
-        tbq, tbk = autotune.pick_flash_blocks(sq, sk, d, interpret=interpret,
-                                              measure=measure)
+        tbq, tbk = autotune.pick_flash_blocks(sq, sk, d, interpret=interpret)
         bq, bk = bq or tbq, bk or tbk
     bq = min(bq, sq)
     bk = min(bk, sk)

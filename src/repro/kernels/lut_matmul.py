@@ -87,16 +87,18 @@ def _decode_tile(packed_ref, codebook, bk: int, bn: int, out_dtype,
     interleaves use stack/reshape which lower to cheap vector shuffles; the
     3-bit variant first splices each 3-byte group into one 24-bit word.
     """
-    packed = packed_ref[...]                              # (bk*nbits//8, bn) uint8
+    # widen before any shift or mask: Mosaic cannot legalize shifts on
+    # uint8 vectors, and the int32 fields are the same bits
+    packed = packed_ref[...].astype(jnp.int32)            # (bk*nbits//8, bn)
     if nbits == 4:
-        lo = (packed & 0xF).astype(jnp.int32)
-        hi = (packed >> 4).astype(jnp.int32)
+        lo = packed & 0xF
+        hi = packed >> 4
         codes = jnp.stack([lo, hi], axis=1).reshape(bk, bn)  # row 2i->lo, 2i+1->hi
     elif nbits == 2:
-        parts = [((packed >> (2 * j)) & 0x3).astype(jnp.int32) for j in range(4)]
+        parts = [(packed >> (2 * j)) & 0x3 for j in range(4)]
         codes = jnp.stack(parts, axis=1).reshape(bk, bn)  # row 4i+j -> field j
     else:  # nbits == 3: rows [3g, 3g+1, 3g+2] are one 24-bit little-endian word
-        grp = packed.reshape(bk // 8, 3, bn).astype(jnp.int32)
+        grp = packed.reshape(bk // 8, 3, bn)
         word = grp[:, 0] | (grp[:, 1] << 8) | (grp[:, 2] << 16)
         parts = [(word >> (3 * j)) & 0x7 for j in range(8)]
         codes = jnp.stack(parts, axis=1).reshape(bk, bn)  # row 8g+j -> field j

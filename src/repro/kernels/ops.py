@@ -14,14 +14,16 @@ from __future__ import annotations
 
 import contextlib
 import functools
-from typing import Optional
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro.core.api import ClusteredTensor, clustered_matmul
 from repro.core.lut import pack_codes_jax, packed_rows, padded_d_in
+from repro.distributed.sharding import kernel_mesh, logical_to_spec
 from repro.kernels import autotune
 from repro.kernels.lut_matmul import (KC, lut_matmul_f32, lut_matmul_fused,
                                       lut_matmul_fused_gemv,
@@ -34,58 +36,15 @@ from repro.utils import round_up
 # §11); kept under the historical name — tests pin its GEMV-awareness
 _pick_blocks = autotune.heuristic_blocks
 
-_LUT_KERNELS = {
-    "lut_f32": lut_matmul_f32,
-    "lut_int8": lut_matmul_int8,
-    "lut_fused": lut_matmul_fused,
-    "lut_fused_gemv": lut_matmul_fused_gemv,
-}
-
-
-def _lut_measure_fn(variant: str, m: int, k: int, n: int, nbits: int):
-    """measure(bm, bn, bk) -> seconds for one LUT kernel variant, on
-    synthetic operands at the (already group-padded) problem size — built
-    only on a compiled backend; interpret mode never measures (DESIGN.md
-    §11). Operands are synthesized (the real ones are tracers when the
-    wrapper is being jit-traced): timing depends on shapes, not values."""
-    kern = _LUT_KERNELS[variant]
-    rng = np.random.default_rng(0)
-    cb = jnp.asarray(np.linspace(-0.05, 0.05, KC).astype(np.float32))
-    codes = rng.integers(0, 1 << nbits, size=(k, n)).astype(np.uint8)
-
-    def measure(bm: int, bn: int, bk: int) -> float:
-        mp, kp, np_ = round_up(m, bm), round_up(k, bk), round_up(n, bn)
-        packed = jax.block_until_ready(pack_codes_jax(
-            jnp.asarray(np.pad(codes, ((0, kp - k), (0, np_ - n)))), nbits))
-        if variant == "lut_int8":
-            x = jnp.asarray(rng.integers(-127, 128, size=(mp, kp))
-                            .astype(np.int8))
-            fn = lambda: kern(x, packed, cb, jnp.float32(0.02), bm=bm, bn=bn,
-                              bk=bk, interpret=False, nbits=nbits)
-        elif variant == "lut_f32":
-            x = jnp.asarray(rng.normal(size=(mp, kp)).astype(np.float32))
-            fn = lambda: kern(x, packed, cb, bm=bm, bn=bn, bk=bk,
-                              interpret=False, nbits=nbits)
-        else:
-            x = jnp.asarray(rng.normal(size=(mp, kp)).astype(np.float32))
-            inv = jnp.ones((kp,), jnp.float32)
-            fn = lambda: kern(x, inv, packed, cb, quantize=True, bm=bm, bn=bn,
-                              bk=bk, interpret=False, nbits=nbits)
-        return autotune.measure_candidate(fn)
-
-    return measure
-
-
 def _blocks_for(variant: str, m: int, k: int, n: int, nbits: int,
                 interpret: bool):
-    """Autotuned (bm, bn, bk) for one wrapper call: cached winner when the
-    tuner has measured this key, measured on a compiled backend at first
-    sight, exactly `_pick_blocks` under the interpreter (DESIGN.md §11)."""
-    measure = None
-    if not interpret and jax.default_backend() == "tpu":
-        measure = _lut_measure_fn(variant, m, k, n, nbits)
-    return autotune.pick_blocks(m, k, n, nbits=nbits, variant=variant,
-                                interpret=interpret, measure=measure)
+    """(bm, bn, bk) for one wrapper call: the autotuner's cached winner for
+    this key, else exactly `_pick_blocks` (DESIGN.md §11). Called while the
+    wrapper's jit traces, so it never measures."""
+    blocks = autotune.pick_blocks(m, k, n, nbits=nbits, variant=variant,
+                                  interpret=interpret)
+    _log_blocks(variant, m, k, n, blocks)
+    return blocks
 
 
 def pad_for_kernel(x: jax.Array, packed: jax.Array, bm: int, bk: int, bn: int,
@@ -210,37 +169,6 @@ def lut_gemm_fused(
 # Fused multi-projection serving GEMM/GEMV (DESIGN.md §15)
 # ---------------------------------------------------------------------------
 
-def _lut_multi_measure_fn(variant: str, m: int, k: int, widths, nbits):
-    """measure(bm, bn, bk) -> seconds for the fused multi kernel on synthetic
-    per-projection operands — built only on a compiled backend."""
-    kern = (lut_matmul_fused_multi_gemv
-            if variant == "lut_fused_multi_gemv" else lut_matmul_fused_multi)
-    rng = np.random.default_rng(0)
-    cb = jnp.asarray(
-        np.stack([np.linspace(-0.05, 0.05, KC)] * len(widths))
-        .astype(np.float32))
-    quantize = tuple(True for _ in widths)
-
-    def measure(bm: int, bn: int, bk: int) -> float:
-        mp, kp = round_up(m, bm), round_up(k, bk)
-        wps = tuple(round_up(w, bn) for w in widths)
-        packed = [jax.block_until_ready(pack_codes_jax(
-            jnp.asarray(rng.integers(0, 1 << nb, size=(kp, wp))
-                        .astype(np.uint8)), nb))
-            for wp, nb in zip(wps, nbits)]
-        x = jnp.asarray(rng.normal(size=(mp, kp)).astype(np.float32))
-        inv = jnp.ones((len(widths), kp), jnp.float32)
-        kw = dict(widths=wps, quantize=quantize, bn=bn, bk=bk,
-                  interpret=False, nbits=tuple(nbits))
-        if variant == "lut_fused_multi_gemv":
-            fn = lambda: kern(x, inv, cb, *packed, bm=mp, **kw)
-        else:
-            fn = lambda: kern(x, inv, cb, *packed, bm=bm, **kw)
-        return autotune.measure_candidate(fn)
-
-    return measure
-
-
 def _multi_blocks(m: int, k: int, widths, nbits, interpret: bool):
     """(bm, bn, bk) for a fused multi call, or None when the projections'
     heuristic bn choices disagree (the wrapper then falls back to unfused
@@ -250,20 +178,23 @@ def _multi_blocks(m: int, k: int, widths, nbits, interpret: bool):
     the SAME (bm, bk) every unfused call gets (they depend only on m and k)
     and the SAME bn (agreement enforced), which is what makes the fused
     output bit-equal to the unfused one on the CPU parity lanes. On a
-    compiled TPU backend the multi variant autotunes under its own cache
-    key (`lut_fused_multi[_gemv]`, P-aware VMEM budget)."""
+    compiled backend a cached winner under the multi variant's own key
+    (`lut_fused_multi[_gemv]`, P-aware VMEM budget) is served, else the
+    same per-projection heuristic. Never measures (called while the
+    wrapper's jit traces)."""
     bns = {autotune.heuristic_blocks(m, k, n)[1] for n in widths}
     if len(bns) > 1:
         return None
     bm, _, bk = autotune.heuristic_blocks(m, k, widths[0])
     bn = bns.pop()
     variant = "lut_fused_multi_gemv" if m < 128 else "lut_fused_multi"
-    if not interpret and jax.default_backend() == "tpu":
-        measure = _lut_multi_measure_fn(variant, m, k, widths, nbits)
-        return autotune.pick_blocks(
-            m, k, sum(widths), nbits=max(nbits), variant=variant,
-            interpret=False, measure=measure, n_ops=len(widths))
-    return bm, bn, bk
+    blocks = (bm, bn, bk)
+    if not interpret:
+        key = autotune.normalize_key(m, k, sum(widths), max(nbits), variant,
+                                     jax.default_backend(), len(widths))
+        blocks = autotune.get_cache().get(key) or blocks
+    _log_blocks(variant, m, k, sum(widths), blocks)
+    return blocks
 
 
 @functools.partial(
@@ -391,15 +322,27 @@ def _resolve_mode(use_kernel: Optional[bool]) -> str:
 # here while a `track_lut_launches` context is open. Counting happens at
 # trace time — jit replays the same launch sequence every step, so one
 # traced step IS the per-step launch count.
-_LAUNCH_LOG: Optional[list] = None
+class LaunchLog(list):
+    """The tags of the LUT launches traced in a `track_lut_launches`
+    context, plus `blocks`: {(variant, m, k, n): (bm, bn, bk)} for every
+    kernel problem the wrappers resolved there. The jitted wrappers resolve
+    blocks only when they first trace a shape, so open the context before
+    the process's first trace of the step to see them all."""
+
+    def __init__(self):
+        super().__init__()
+        self.blocks: Dict[Tuple[str, int, int, int], Tuple[int, ...]] = {}
+
+
+_LAUNCH_LOG: Optional[LaunchLog] = None
 
 
 @contextlib.contextmanager
 def track_lut_launches():
     """Collect the LUT kernel launches of everything traced inside the
-    context; yields the list of tags (e.g. 'fused_multi[3]', 'fused')."""
+    context; yields the LaunchLog of tags (e.g. 'fused_multi[3]', 'fused')."""
     global _LAUNCH_LOG
-    prev, _LAUNCH_LOG = _LAUNCH_LOG, []
+    prev, _LAUNCH_LOG = _LAUNCH_LOG, LaunchLog()
     try:
         yield _LAUNCH_LOG
     finally:
@@ -411,15 +354,79 @@ def _log_launch(tag: str) -> None:
         _LAUNCH_LOG.append(tag)
 
 
+def _log_blocks(variant: str, m: int, k: int, n: int, blocks) -> None:
+    if _LAUNCH_LOG is not None:
+        _LAUNCH_LOG.blocks[(variant, m, k, n)] = tuple(blocks)
+
+
+# ---------------------------------------------------------------------------
+# Kernels on a multi-device serving mesh (DESIGN.md §14)
+# ---------------------------------------------------------------------------
+
+def _model_split(split: Optional[str], mesh, k: int, ns) -> Optional[str]:
+    """The weight dim the mesh's model axis shards, as `auto_shard` placed
+    it: "out" (column-parallel: N of every projection divides), "in"
+    (row-parallel: K divides into whole packing groups, so every packed
+    shard starts on a group boundary), or None (replicated)."""
+    mp = mesh.shape.get("model", 1)
+    if mp == 1 or split is None:
+        return None
+    if split == "out" and all(n % mp == 0 for n in ns):
+        return "out"
+    if split == "in" and k % mp == 0 and (k // mp) % 8 == 0:
+        return "in"
+    return None
+
+
+def _per_device(fn, mesh, split: Optional[str]):
+    """Wrap `fn(x2, inv, cb, act, *packed) -> tuple of outputs` to run per
+    device of `mesh` under shard_map. The rows of x2 (batch or request-slot
+    rows) stay split over the data axes as the rules place them, so each
+    device computes only its own rows. Column-parallel weights compute
+    their local output columns; row-parallel weights contract their local K
+    slice (x2 columns, the last dim of `inv`, packed rows) and psum the f32
+    partials over the model axis; other weights run replicated over the
+    model axis."""
+    def call(x2, inv, cb, act, *packed):
+        n_ops = len(packed)
+        rows = logical_to_spec(x2.shape[:1], ("batch",))[0]
+        if split == "out":
+            in_specs = (P(rows), P(), P(), P()) + (P(None, "model"),) * n_ops
+            out_specs = (P(rows, "model"),) * n_ops
+            body = fn
+        elif split == "in":
+            k_spec = P(*(None,) * (inv.ndim - 1), "model")
+            in_specs = ((P(rows, "model"), k_spec, P(), P())
+                        + (P("model", None),) * n_ops)
+            out_specs = (P(rows),) * n_ops
+
+            def body(*args):
+                return tuple(jax.lax.psum(y, "model") for y in fn(*args))
+        else:
+            in_specs = (P(rows),) + (P(),) * (3 + n_ops)
+            out_specs = (P(rows),) * n_ops
+            body = fn
+        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)(
+            x2, inv, cb, act, *packed)
+    return call
+
+
 def clustered_linear(
     x: jax.Array,
     ct: ClusteredTensor,
     *,
     use_kernel: Optional[bool] = None,
+    split: Optional[str] = None,
 ) -> jax.Array:
     """Model-facing clustered matmul. use_kernel=None auto-selects (see
     lut_serving): the fused Pallas path on TPU backends, the gather
-    contraction elsewhere (identical numerics)."""
+    contraction elsewhere (identical numerics).
+
+    `split` names the weight dim a serving mesh's model axis shards —
+    "out" for column-parallel, "in" for row-parallel projections
+    (DESIGN.md §14); on a multi-device mesh the kernel then runs per device
+    on its local shard (`_per_device`). None runs it replicated there."""
     mode = _resolve_mode(use_kernel)
     if mode == "ref" or ct.codebook.ndim != 1:
         # stacked/expert codebooks take the gather path (vmapped in models)
@@ -427,10 +434,19 @@ def clustered_linear(
     inv, act, quantize = _transform_params(ct)
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
+    packed = packed_view(ct)
     _log_launch("fused")
-    y = lut_gemm_fused(x2, inv, packed_view(ct), ct.codebook, act,
-                       quantize=quantize, interpret=(mode == "interpret"),
-                       nbits=ct.nbits)
+
+    def run(x2, inv, cb, act, packed):
+        return (lut_gemm_fused(x2, inv, packed, cb, act, quantize=quantize,
+                               interpret=(mode == "interpret"),
+                               nbits=ct.nbits),)
+
+    mesh = kernel_mesh()
+    if mesh is not None:
+        run = _per_device(run, mesh, _model_split(
+            split, mesh, x2.shape[1], (packed.shape[1],)))
+    (y,) = run(x2, inv, ct.codebook, act, packed)
     return y.reshape(*lead, -1).astype(x.dtype)
 
 
@@ -452,16 +468,21 @@ def clustered_linear_multi(
     projection, or projections whose heuristic bn disagree."""
     cts = tuple(cts)
     mode = _resolve_mode(use_kernel)
+    mesh = kernel_mesh()
     fusable = (mode != "ref" and len(cts) > 1
                and all(ct.codebook.ndim == 1 for ct in cts))
     if fusable:
         m = int(np.prod(x.shape[:-1])) if x.ndim > 1 else 1
         n_true = tuple(int(packed_view(ct).shape[1]) for ct in cts)
         nbits = tuple(ct.nbits for ct in cts)
+        split = (None if mesh is None
+                 else _model_split("out", mesh, x.shape[-1], n_true))
+        if split == "out":       # the kernel sees each device's columns
+            n_true = tuple(n // mesh.shape["model"] for n in n_true)
         fusable = _multi_blocks(m, x.shape[-1], n_true, nbits, True) is not None
     if not fusable:
-        return tuple(clustered_linear(x, ct, use_kernel=use_kernel)
-                     for ct in cts)
+        return tuple(clustered_linear(x, ct, use_kernel=use_kernel,
+                                      split="out") for ct in cts)
     params = [_transform_params(ct) for ct in cts]
     inv_stack = jnp.stack([inv for inv, _, _ in params])
     act_stack = jnp.stack([act.astype(jnp.float32) for _, act, _ in params])
@@ -470,7 +491,14 @@ def clustered_linear_multi(
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
     _log_launch(f"fused_multi[{len(cts)}]")
-    ys = lut_gemm_fused_multi(
-        x2, inv_stack, cb_stack, act_stack, *[packed_view(ct) for ct in cts],
-        quantize=quantize, interpret=(mode == "interpret"), nbits=nbits)
+
+    def run(x2, inv_stack, cb_stack, act_stack, *packed):
+        return lut_gemm_fused_multi(
+            x2, inv_stack, cb_stack, act_stack, *packed, quantize=quantize,
+            interpret=(mode == "interpret"), nbits=nbits)
+
+    if mesh is not None:
+        run = _per_device(run, mesh, split)
+    ys = run(x2, inv_stack, cb_stack, act_stack,
+             *[packed_view(ct) for ct in cts])
     return tuple(y.reshape(*lead, -1).astype(x.dtype) for y in ys)

@@ -22,9 +22,8 @@ window arrive as data, so the engine's bounded-trace contract is untouched.
 
 Correctness is asserted against the pure-jnp oracle
 `kernels/ref.py paged_dequant_attention_ref` in interpret mode on CPU
-(tests/test_paged_kv.py) — the kernel body uses only full-block reads, which
-this JAX build's interpreter supports (unlike the dynamic `pl.load` indexing
-of kernels/flash_attention.py, whose interpreter tests are known-red).
+(tests/test_paged_kv.py). The engine serves through the pool-direct
+`paged_pool_attention` below; this gathered-view kernel is not on that path.
 
 `paged_attention_mode(...)` mirrors `kernels/ops.py lut_serving`: it forces
 how `models/layers.py paged_attn_block` consumes an int8 cache —
@@ -42,7 +41,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
+from repro.distributed.sharding import kernel_mesh, logical_to_spec
 from repro.kernels import autotune
 from repro.utils import round_up
 
@@ -82,31 +83,6 @@ def resolved_paged_attention_mode() -> str:
 # ---------------------------------------------------------------------------
 # Kernel
 # ---------------------------------------------------------------------------
-
-def _paged_measure_fn(s_slots: int, t: int, h: int, d: int, l: int, kv: int,
-                      dtype, softcap: float):
-    """measure(l_pad) -> seconds on a synthetic int8 KV view — built only on
-    a compiled backend (DESIGN.md §11); timing depends on shapes, not the
-    cache contents."""
-    rng = np.random.default_rng(0)
-    q = jnp.asarray(rng.normal(size=(s_slots, t, h, d)), dtype)
-    kq = jnp.asarray(rng.integers(-127, 128, size=(s_slots, l, kv, d)),
-                     jnp.int8)
-    vq = jnp.asarray(rng.integers(-127, 128, size=(s_slots, l, kv, d)),
-                     jnp.int8)
-    sc = jnp.ones((s_slots, l, kv), jnp.float32) * 0.01
-    sm = jnp.ones((kv, d), jnp.float32)
-    lengths = jnp.full((s_slots,), max(l - t, 0), jnp.int32)
-    n_new = jnp.full((s_slots,), t, jnp.int32)
-
-    def measure(l_pad: int) -> float:
-        return autotune.measure_candidate(
-            lambda: paged_dequant_attention(
-                q, kq, sc, vq, sc, sm, sm, lengths, n_new,
-                jnp.int32(0), softcap=softcap, interpret=False, l_pad=l_pad))
-
-    return measure
-
 
 def _paged_dequant_kernel(q_ref, kq_ref, ks_ref, vq_ref, vs_ref, ksm_ref,
                           vsm_ref, len_ref, nnew_ref, win_ref, o_ref, *,
@@ -162,7 +138,7 @@ def paged_dequant_attention(
     *,
     softcap: float = 0.0,
     interpret: bool = False,
-    l_pad: Optional[int] = None,   # lane multiple for L; None -> autotuned
+    l_pad: Optional[int] = None,   # lane multiple for L; None -> cache/heuristic
 ) -> jax.Array:
     """Fused dequantize + masked attention over a slot's gathered int8 KV.
 
@@ -174,12 +150,7 @@ def paged_dequant_attention(
     g = h // kv
     gt = g * t
     if l_pad is None:
-        measure = None
-        if not interpret and jax.default_backend() == "tpu":
-            measure = _paged_measure_fn(s_slots, t, h, d, l, kv, q.dtype,
-                                        softcap)
-        l_pad = autotune.pick_paged_pad(gt, l, d, interpret=interpret,
-                                        measure=measure)
+        l_pad = autotune.pick_paged_pad(gt, l, d, interpret=interpret)
 
     # (S, T, H, D) -> (S, KV, g, T, D) -> (S, KV, g*T, D): row r = gi*T + t
     qt = q.reshape(s_slots, t, kv, g, d).transpose(0, 2, 3, 1, 4)
@@ -244,24 +215,30 @@ def paged_dequant_attention(
 # per-slot lengths ride in as SCALAR-PREFETCH operands
 # (pltpu.PrefetchScalarGridSpec), so each grid step's index map computes
 # which physical pool block to DMA — the kernel reads the paged pools
-# IN PLACE. Dead iterations (past a slot's live block count) clamp their
-# index to the last live block, which Pallas recognizes as "same block, no
-# re-DMA", and a `pl.when(b < live)` guard skips their compute; the softmax
-# is the standard online (flash) accumulation across a slot's blocks.
+# IN PLACE, one whole block (every kv head) per grid step. Dead iterations
+# (past a slot's live block count) clamp their index to the last live block,
+# which Pallas recognizes as "same block, no re-DMA", and a
+# `pl.when(b < live)` guard skips their compute; the softmax is the standard
+# online (flash) accumulation across a slot's blocks.
 
 def _pool_kernel(bt_ref, len_ref, nnew_ref, win_ref, q_ref, *rest,
-                 t: int, bs: int, d: int, gt: int, nb_grid: int,
+                 t: int, bs: int, kv: int, d: int, gt: int, nb_grid: int,
                  scale: float, softcap: float, int8_kv: bool):
-    """One (slot, kv-head, block) program: attend the slot's query rows over
-    ONE physical cache block, accumulating online-softmax partials in VMEM
-    scratch; the output DMAs once, at the slot's last block iteration."""
+    """One (slot, block) program: attend every kv head's query rows over ONE
+    physical cache block, accumulating online-softmax partials in VMEM
+    scratch; the output DMAs once, at the slot's last block iteration.
+
+    The block arrives whole — (bs, KV, D), every kv head — because a
+    per-head block (bs, 1, D) would put a 1 against KV in the tiled
+    second-minor dimension, which Mosaic cannot lay out. The heads are
+    a static loop over that VMEM tile."""
     if int8_kv:
         (kq_ref, ks_ref, vq_ref, vs_ref, ksm_ref, vsm_ref,
          o_ref, acc_ref, m_ref, l_ref) = rest
     else:
         k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = rest
     s_i = pl.program_id(0)
-    b = pl.program_id(2)
+    b = pl.program_id(1)
 
     @pl.when(b == 0)
     def _init():
@@ -275,63 +252,70 @@ def _pool_kernel(bt_ref, len_ref, nnew_ref, win_ref, q_ref, *rest,
 
     @pl.when(b < live)
     def _block():
-        q = q_ref[...].reshape(gt, d).astype(jnp.float32) * scale
-        if int8_kv:
-            k = (kq_ref[...].reshape(bs, d).astype(jnp.float32)
-                 * ks_ref[...].reshape(bs, 1) * ksm_ref[...].reshape(1, d))
-            v = (vq_ref[...].reshape(bs, d).astype(jnp.float32)
-                 * vs_ref[...].reshape(bs, 1) * vsm_ref[...].reshape(1, d))
-        else:
-            k = k_ref[...].reshape(bs, d).astype(jnp.float32)
-            v = v_ref[...].reshape(bs, d).astype(jnp.float32)
-        s_blk = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # (gt, bs)
-        if softcap > 0:
-            s_blk = softcap * jnp.tanh(s_blk / softcap)
-
         window = win_ref[0]
         weff = jnp.where(window > 0, window, 1 << 30)
         rows = jax.lax.broadcasted_iota(jnp.int32, (gt, bs), 0)
         cols = b * bs + jax.lax.broadcasted_iota(jnp.int32, (gt, bs), 1)
         q_pos = length + rows % t      # q rows are (group, T) flattened
         mask = (q_pos >= cols) & ((q_pos - cols) < weff) & (cols < total)
-        s_blk = jnp.where(mask, s_blk, NEG_INF)
+        if int8_kv:
+            ks = ks_ref[0].astype(jnp.float32)             # (bs, KV)
+            vs = vs_ref[0].astype(jnp.float32)
+            ksm = ksm_ref[...].astype(jnp.float32)         # (KV, D)
+            vsm = vsm_ref[...].astype(jnp.float32)
+        for h in range(kv):
+            q = q_ref[0, h].astype(jnp.float32) * scale   # (gt, D)
+            if int8_kv:
+                k = (kq_ref[0, :, h, :].astype(jnp.float32)
+                     * ks[:, h:h + 1] * ksm[h:h + 1, :])
+                v = (vq_ref[0, :, h, :].astype(jnp.float32)
+                     * vs[:, h:h + 1] * vsm[h:h + 1, :])
+            else:
+                k = k_ref[0, :, h, :].astype(jnp.float32)  # (bs, D)
+                v = v_ref[0, :, h, :].astype(jnp.float32)
+            s_blk = jax.lax.dot_general(                   # (gt, bs) = q k^T
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            if softcap > 0:
+                s_blk = softcap * jnp.tanh(s_blk / softcap)
+            s_blk = jnp.where(mask, s_blk, NEG_INF)
 
-        m_prev = m_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s_blk, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s_blk - m_new) * mask.astype(jnp.float32)
-        l_new = l_ref[:, :1] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = (acc_ref[...] * alpha
-                        + jnp.dot(p, v, preferred_element_type=jnp.float32))
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+            m_prev = m_ref[h][:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s_blk, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s_blk - m_new) * mask.astype(jnp.float32)
+            l_new = l_ref[h][:, :1] * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[h] = (acc_ref[h] * alpha
+                          + jnp.dot(p, v, preferred_element_type=jnp.float32))
+            m_ref[h] = jnp.broadcast_to(m_new, (gt, m_ref.shape[-1]))
+            l_ref[h] = jnp.broadcast_to(l_new, (gt, l_ref.shape[-1]))
 
     @pl.when(b == nb_grid - 1)
     def _done():
-        denom = jnp.maximum(l_ref[:, :1], 1e-30)   # all-masked rows -> 0 out
-        o_ref[...] = (acc_ref[...] / denom).reshape(1, 1, gt, d).astype(
-            o_ref.dtype)
+        denom = jnp.maximum(l_ref[:, :, :1], 1e-30)  # all-masked rows -> 0 out
+        o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+
+
+def _live_block(s, b, bt_ref, len_ref, nnew_ref, nb: int, bs: int):
+    """The physical block grid step (s, b) reads: the slot's b-th table entry,
+    clamped past the slot's live count to its last live block — an index
+    identical to the previous step's, so Pallas skips the DMA."""
+    total = len_ref[s] + nnew_ref[s]
+    live = jnp.maximum(jax.lax.div(total + bs - 1, bs), 1)
+    return jnp.clip(bt_ref[s, jnp.minimum(b, live - 1)], 0, nb - 1)
 
 
 def _pool_block_map(nb: int, bs: int):
-    """Index map for the K/V pool operands: scalar-prefetched block table +
-    lengths pick the physical block this grid step reads. Past the slot's
-    live count the index clamps to the last live block — an identical index
-    to the previous iteration, so Pallas skips the DMA."""
-    def imap(s, h, b, bt_ref, len_ref, nnew_ref, win_ref):
-        total = len_ref[s] + nnew_ref[s]
-        live = jnp.maximum(jax.lax.div(total + bs - 1, bs), 1)
-        bid = bt_ref[s, jnp.minimum(b, live - 1)]
-        return (jnp.clip(bid, 0, nb - 1), 0, h, 0)
+    """Index map for the (nb, bs, KV, D) K/V pools."""
+    def imap(s, b, bt_ref, len_ref, nnew_ref, win_ref):
+        return (_live_block(s, b, bt_ref, len_ref, nnew_ref, nb, bs), 0, 0, 0)
     return imap
 
 
 def _pool_scale_map(nb: int, bs: int):
-    def imap(s, h, b, bt_ref, len_ref, nnew_ref, win_ref):
-        total = len_ref[s] + nnew_ref[s]
-        live = jnp.maximum(jax.lax.div(total + bs - 1, bs), 1)
-        bid = bt_ref[s, jnp.minimum(b, live - 1)]
-        return (jnp.clip(bid, 0, nb - 1), 0, h)
+    """Index map for the (nb, bs, KV) int8 scale pools."""
+    def imap(s, b, bt_ref, len_ref, nnew_ref, win_ref):
+        return (_live_block(s, b, bt_ref, len_ref, nnew_ref, nb, bs), 0, 0)
     return imap
 
 
@@ -354,9 +338,9 @@ def paged_pool_attention(
 ) -> jax.Array:
     """Paged attention reading the block pools IN PLACE (no gather).
 
-    Grid (S, KV, NB) with the block tables, lengths and n_new as
-    scalar-prefetch operands: each (slot, head, block) step DMAs exactly one
-    live physical block out of HBM — per decode step the cache traffic is
+    Grid (S, NB) with the block tables, lengths and n_new as
+    scalar-prefetch operands: each (slot, block) step DMAs exactly one
+    live physical block (all kv heads) out of HBM — per decode step the cache traffic is
     each slot's true length, not the table-width-padded gathered copy the
     `paged_dequant_attention` path pays before it even starts. Works on
     float and int8 pools (int8 dequantizes per-block in VMEM; pass the scale
@@ -380,40 +364,40 @@ def paged_pool_attention(
     if gt_p != gt:
         qt = jnp.pad(qt, ((0, 0), (0, 0), (0, gt_p - gt), (0, 0)))
 
-    grid = (s_slots, kv, nb_grid)
+    grid = (s_slots, nb_grid)
     in_specs = [
-        pl.BlockSpec((1, 1, gt_p, d), lambda s, hh, b, bt, ln, nn, w:
-                     (s, hh, 0, 0)),
-        pl.BlockSpec((1, bs, 1, d), _pool_block_map(nb, bs)),
+        pl.BlockSpec((1, kv, gt_p, d), lambda s, b, bt, ln, nn, w:
+                     (s, 0, 0, 0)),
+        pl.BlockSpec((1, bs, kv, d), _pool_block_map(nb, bs)),
     ]
     operands = [qt, k_pool]
     if int8_kv:
-        in_specs += [pl.BlockSpec((1, bs, 1), _pool_scale_map(nb, bs))]
+        in_specs += [pl.BlockSpec((1, bs, kv), _pool_scale_map(nb, bs))]
         operands += [k_scale]
-    in_specs += [pl.BlockSpec((1, bs, 1, d), _pool_block_map(nb, bs))]
+    in_specs += [pl.BlockSpec((1, bs, kv, d), _pool_block_map(nb, bs))]
     operands += [v_pool]
     if int8_kv:
         in_specs += [
-            pl.BlockSpec((1, bs, 1), _pool_scale_map(nb, bs)),
-            pl.BlockSpec((1, d), lambda s, hh, b, bt, ln, nn, w: (hh, 0)),
-            pl.BlockSpec((1, d), lambda s, hh, b, bt, ln, nn, w: (hh, 0)),
+            pl.BlockSpec((1, bs, kv), _pool_scale_map(nb, bs)),
+            pl.BlockSpec((kv, d), lambda s, b, bt, ln, nn, w: (0, 0)),
+            pl.BlockSpec((kv, d), lambda s, b, bt, ln, nn, w: (0, 0)),
         ]
         operands += [v_scale, k_smooth.astype(jnp.float32),
                      v_smooth.astype(jnp.float32)]
 
     kernel = functools.partial(
-        _pool_kernel, t=t, bs=bs, d=d, gt=gt_p, nb_grid=nb_grid,
+        _pool_kernel, t=t, bs=bs, kv=kv, d=d, gt=gt_p, nb_grid=nb_grid,
         scale=1.0 / np.sqrt(d), softcap=softcap, int8_kv=int8_kv)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, gt_p, d),
-                               lambda s, hh, b, bt, ln, nn, w: (s, hh, 0, 0)),
+        out_specs=pl.BlockSpec((1, kv, gt_p, d),
+                               lambda s, b, bt, ln, nn, w: (s, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((gt_p, d), jnp.float32),
-            pltpu.VMEM((gt_p, 128), jnp.float32),
-            pltpu.VMEM((gt_p, 128), jnp.float32),
+            pltpu.VMEM((kv, gt_p, d), jnp.float32),
+            pltpu.VMEM((kv, gt_p, 128), jnp.float32),
+            pltpu.VMEM((kv, gt_p, 128), jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -429,3 +413,46 @@ def paged_pool_attention(
 
     out = out[:, :, :gt].reshape(s_slots, kv, g, t, d)
     return out.transpose(0, 3, 1, 2, 4).reshape(s_slots, t, h, d)
+
+
+def mesh_paged_pool_attention(q, k_pool, v_pool, block_tables, lengths,
+                              n_new, window, *, k_scale=None, v_scale=None,
+                              k_smooth=None, v_smooth=None,
+                              softcap: float = 0.0, interpret: bool = False):
+    """`paged_pool_attention` on the installed serving mesh (DESIGN.md §14).
+
+    One device: the kernel itself. On a multi-device mesh the kernel runs
+    per device under shard_map, because GSPMD cannot partition a Pallas
+    call. Each device attends only its own request slots (q, block tables,
+    lengths split over the data axes as the rules place slots); every
+    device holds every block. When the model axis divides the kv heads,
+    each device attends its kv-head shard of q and of the pools (the layout
+    `cache_shardings` gives them); otherwise every device attends all
+    heads over pools replicated on the model axis."""
+    kw = dict(softcap=softcap, interpret=interpret)
+    mesh = kernel_mesh()
+    if mesh is None:
+        return paged_pool_attention(
+            q, k_pool, v_pool, block_tables, lengths, n_new, window,
+            k_scale=k_scale, v_scale=v_scale, k_smooth=k_smooth,
+            v_smooth=v_smooth, **kw)
+    mp = mesh.shape.get("model", 1)
+    hs = "model" if mp > 1 and k_pool.shape[2] % mp == 0 else None
+    sl = logical_to_spec(q.shape[:1], ("slots",))[0]
+    pool, q_spec = P(None, None, hs, None), P(sl, None, hs, None)
+    args = [q, k_pool, v_pool, block_tables, lengths, n_new,
+            jnp.asarray(window, jnp.int32)]
+    specs = [q_spec, pool, pool, P(sl), P(sl), P(sl), P()]
+    if k_pool.dtype == jnp.int8:
+        args += [k_scale, v_scale, k_smooth, v_smooth]
+        specs += [P(None, None, hs), P(None, None, hs), P(hs, None),
+                  P(hs, None)]
+
+    def body(q, kp, vp, bt, ln, nn, w, *int8):
+        ks, vs, ksm, vsm = int8 or (None,) * 4
+        return paged_pool_attention(q, kp, vp, bt, ln, nn, w, k_scale=ks,
+                                    v_scale=vs, k_smooth=ksm, v_smooth=vsm,
+                                    **kw)
+
+    return jax.shard_map(body, mesh=mesh, in_specs=tuple(specs),
+                         out_specs=q_spec, check_vma=False)(*args)

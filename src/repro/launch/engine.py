@@ -97,7 +97,7 @@ import numpy as np
 
 from repro.core.api import compress_model, is_clustered
 from repro.distributed.sharding import use_rules
-from repro.launch.mesh import make_host_mesh
+from repro.launch.mesh import auto_mesh, make_host_mesh
 from repro.models.config import get_config, reduced
 from repro.models.registry import (CAP_ENCODER, CAP_INT8_KV, CAP_PAGED,
                                    CAP_PREFIX_CACHE, CAP_SLOT_STATE,
@@ -949,9 +949,18 @@ class ServingEngine:
     # -- scheduler ----------------------------------------------------------
 
     def step(self) -> List[Request]:
-        """One scheduler iteration: admit from the queue, run one traced
-        step over every active slot, harvest finished requests. Returns the
-        requests that finished during this step.
+        """One scheduler iteration: admit from the queue, advance every
+        active slot once, harvest finished requests. Returns the requests
+        that finished during this step.
+
+        Prefilling slots advance by one chunk through the width-
+        `prefill_chunk` computation; decoding slots advance one token
+        through the width-1 computation, in a second traced step when both
+        kinds are active. A decode token is therefore always computed by the
+        same program whether or not another request is prefilling: the two
+        widths compile to different programs, on a v5e they were seen to
+        round the same row differently, and a token must not depend on its
+        neighbours (the engine-vs-solo contract, DESIGN.md §5).
 
         In speculative mode a pure-decode step becomes a draft/verify ROUND
         (`_spec_round`): k draft tokens from the 2-bit model, one batched
@@ -964,8 +973,23 @@ class ServingEngine:
             return []
         if self.spec_k and not any(r.prefilling for _, r in active):
             return self._spec_round(active)
+        prefilling = {r.rid for _, r in active if r.prefilling}
+        if self.spec_k or len(prefilling) in (0, len(active)):
+            done = self._step_width(
+                self.ecfg.prefill_chunk if prefilling else 1, None)
+        else:
+            decoding = {r.rid for _, r in active} - prefilling
+            done = self._step_width(self.ecfg.prefill_chunk, prefilling)
+            done += self._step_width(1, decoding)
+        self.steps += 1
+        return done
+
+    def _step_width(self, t: int, rids) -> List[Request]:
+        """One traced step of width `t` over the active slots whose request
+        id is in `rids` (None: every active slot); the others feed nothing."""
         ecfg = self.ecfg
-        t = ecfg.prefill_chunk if any(r.prefilling for _, r in active) else 1
+        active = [(s, r) for s, r in enumerate(self.slots)
+                  if r is not None and (rids is None or r.rid in rids)]
 
         # pass 1 — reserve blocks (paged families only: slot state is
         # fixed-size, so slot-only families never starve or evict here).
@@ -986,7 +1010,7 @@ class ServingEngine:
         # before any tokens are packed.
         if ecfg.prefix_cache:
             for s, r in enumerate(self.slots):
-                if r is None:
+                if r is None or (rids is not None and r.rid not in rids):
                     continue
                 w = want(r)
                 if len(r.blocks) * ecfg.block_size >= int(self.lengths[s]) + w:
@@ -997,7 +1021,8 @@ class ServingEngine:
         # waits this step, like starvation)
         tokens = np.zeros((ecfg.num_slots, t), np.int32)
         n_new = np.zeros(ecfg.num_slots, np.int32)
-        active = [(s, r) for s, r in enumerate(self.slots) if r is not None]
+        active = [(s, r) for s, r in enumerate(self.slots)
+                  if r is not None and (rids is None or r.rid in rids)]
         for s, r in active:
             w = want(r)
             if self.has_paged and (
@@ -1026,7 +1051,6 @@ class ServingEngine:
                     jnp.asarray(self.lengths), jnp.asarray(n_new),
                     jnp.asarray(self.block_tables))
         next_tok = np.asarray(next_tok)
-        self.steps += 1
 
         done: List[Request] = []
         for s, r in active:
@@ -1763,7 +1787,7 @@ def build_engine(arch: str, *, use_reduced: bool = True, lcd: bool = False,
                     f"build_engine: data_parallel x model_parallel must "
                     f"factor the {n} visible device(s); got "
                     f"{ecfg.data_parallel} x {ecfg.model_parallel}")
-            mesh = jax.make_mesh((dp, mp), ("data", "model"))
+            mesh = auto_mesh((dp, mp), ("data", "model"))
         elif n > 1:
             from repro.distributed.layout import choose_layout
             mesh, layout_report = choose_layout(model, params, ecfg)

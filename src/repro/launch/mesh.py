@@ -18,19 +18,30 @@ from __future__ import annotations
 
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes, *, devices=None):
+    """`jax.make_mesh` with every axis `Auto`. The sharding rules
+    (distributed/sharding.py) annotate arrays and leave propagation to
+    GSPMD; `make_mesh`'s default Explicit axes would instead type every
+    array's sharding, and ops mixing differently-sharded operands (the static
+    decode path's `dynamic_update_slice` into its cache) then fail to trace."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Whatever devices exist locally, as a (data, model) mesh with model=1.
     Used by smoke tests and the CPU end-to-end drivers."""
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return auto_mesh((n, 1), ("data", "model"))
 
 
 def make_elastic_mesh(n_chips: int, *, model_parallel: int = 16,
@@ -50,5 +61,5 @@ def make_elastic_mesh(n_chips: int, *, model_parallel: int = 16,
         pods -= 1
     data = rows // pods
     if pods > 1:
-        return jax.make_mesh((pods, data, model_parallel), ("pod", "data", "model"))
-    return jax.make_mesh((data, model_parallel), ("data", "model"))
+        return auto_mesh((pods, data, model_parallel), ("pod", "data", "model"))
+    return auto_mesh((data, model_parallel), ("data", "model"))

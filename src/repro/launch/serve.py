@@ -33,7 +33,7 @@ import numpy as np
 from repro.launch.engine import (BlockAllocator, EngineConfig, Request,
                                  ServingEngine, build_decode_fns,
                                  build_engine, serve)
-from repro.utils import logger
+from repro.utils import enable_compile_cache, logger
 
 __all__ = ["BlockAllocator", "EngineConfig", "Request", "ServingEngine",
            "build_decode_fns", "build_engine", "serve", "main"]
@@ -211,6 +211,7 @@ def main() -> None:
                          "assignment, packed weight bytes, kv dtype) and "
                          "exit without serving (continuous mode)")
     args = ap.parse_args()
+    logger.info(f"compile cache: {enable_compile_cache()}")
     if args.speculative and not args.continuous:
         ap.error("--speculative requires --continuous")
     if args.kv_dtype and not args.continuous:

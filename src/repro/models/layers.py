@@ -43,17 +43,20 @@ def resolve_weight(w, dtype) -> jax.Array:
     return (dense / w.smooth[..., :, None]).astype(dtype)
 
 
-def linear(x: jax.Array, w, b: Optional[jax.Array] = None) -> jax.Array:
+def linear(x: jax.Array, w, b: Optional[jax.Array] = None,
+           split: Optional[str] = None) -> jax.Array:
     """Dense projection. `w` may be a plain array or an LCD ClusteredTensor —
     the paper's technique is first-class: any projection can serve clustered.
 
     Clustered weights dispatch through kernels.ops.clustered_linear: the fused
     smooth+quant+LUT Pallas GEMM on TPU (or under lut_serving("interpret")),
     the trainable gather contraction elsewhere — so this one entry point
-    covers training, CPU CI, and the serving engine (DESIGN.md §2)."""
+    covers training, CPU CI, and the serving engine (DESIGN.md §2).
+    `split` ("out" column-parallel, "in" row-parallel) tells the kernel
+    which weight dim a serving mesh's model axis shards (DESIGN.md §14)."""
     if is_clustered(w):
         from repro.kernels.ops import clustered_linear
-        y = clustered_linear(x, w)
+        y = clustered_linear(x, w, split=split)
     else:
         y = x @ w.astype(x.dtype)
     if b is not None:
@@ -71,13 +74,14 @@ def linear_group(x: jax.Array, ws, bs, cfg: ModelConfig) -> Tuple[jax.Array, ...
     (DESIGN.md §15). The fused kernel is bit-equal to per-projection calls
     (tests/test_fused_multi.py), so this changes kernel count and HBM
     traffic, never numerics. Any dense weight in the group — or a
-    non-fusable block-shape mix — falls back to independent `linear` calls."""
+    non-fusable block-shape mix — falls back to independent `linear` calls.
+    Every group member is column-parallel on a serving mesh (DESIGN.md §14)."""
     if (cfg.fused_projections and len(ws) > 1
             and all(is_clustered(w) for w in ws)):
         from repro.kernels.ops import clustered_linear_multi
         ys = clustered_linear_multi(x, tuple(ws))
     else:
-        ys = tuple(linear(x, w) for w in ws)
+        ys = tuple(linear(x, w, split="out") for w in ws)
     return tuple(y if b is None else y + b.astype(y.dtype)
                  for y, b in zip(ys, bs))
 
@@ -257,7 +261,7 @@ def attn_block(
         k = rope(k, base + jnp.arange(s), cfg.rope_theta)
         causal = True
     else:
-        q = linear(x, p["wq"], p.get("bq")).reshape(b, s, nh, hd)
+        q = linear(x, p["wq"], p.get("bq"), split="out").reshape(b, s, nh, hd)
         k, v = cross_kv          # precomputed encoder K/V: (B, S_enc, KV, D)
         causal = False
 
@@ -316,7 +320,7 @@ def attn_block(
     o = attention(q, k, v, causal=causal, window=layer_window,
                   softcap=cfg.attn_softcap, q_offset=q_off)
     o = o.reshape(b, s, nh * hd)
-    return linear(o, p["wo"]), new_cache
+    return linear(o, p["wo"], split="in"), new_cache
 
 
 # ---------------------------------------------------------------------------
@@ -415,15 +419,15 @@ def paged_attn_block(
 
     q = maybe_shard(q, "slots", None, None, None)
     from repro.kernels.paged_attention import (
-        paged_pool_attention, resolved_paged_attention_mode)
+        mesh_paged_pool_attention, resolved_paged_attention_mode)
     mode = resolved_paged_attention_mode()
     if mode in ("kernel", "interpret"):
         # pool-direct kernel (float AND int8 pools): the block tables ride
-        # as scalar-prefetch operands and each (slot, head, block) grid step
+        # as scalar-prefetch operands and each (slot, block) grid step
         # DMAs exactly one live physical block — the table-wide
         # `kc[block_tables]` gather below (a full logical-view HBM copy per
         # layer per step) never happens on this path.
-        o = paged_pool_attention(
+        o = mesh_paged_pool_attention(
             q, kc, vc, block_tables, lengths, n_new,
             jnp.asarray(layer_window, jnp.int32),
             k_scale=kc_scale, v_scale=vc_scale,
@@ -431,8 +435,8 @@ def paged_attn_block(
             softcap=cfg.attn_softcap, interpret=(mode == "interpret"))
         o = o.reshape(b, t, nh * hd)
         if int8_kv:
-            return linear(o, p["wo"]), kc, vc, kc_scale, vc_scale
-        return linear(o, p["wo"]), kc, vc
+            return linear(o, p["wo"], split="in"), kc, vc, kc_scale, vc_scale
+        return linear(o, p["wo"], split="in"), kc, vc
 
     if int8_kv:
         # gather each slot's logical view IN INT8 — the cache's HBM read
@@ -456,7 +460,7 @@ def paged_attn_block(
                         window=layer_window, softcap=cfg.attn_softcap,
                         scale=1.0 / np.sqrt(hd), k_len=lengths + n_new)
         o = o.reshape(b, t, nh * hd)
-        return linear(o, p["wo"]), kc, vc, kc_scale, vc_scale
+        return linear(o, p["wo"], split="in"), kc, vc, kc_scale, vc_scale
 
     # gather each slot's logical view: (S, max_blocks*block_size, KV, D)
     kv_k = kc[block_tables].reshape(b, -1, nkv, hd).astype(x.dtype)
@@ -469,7 +473,7 @@ def paged_attn_block(
                     window=layer_window, softcap=cfg.attn_softcap,
                     scale=1.0 / np.sqrt(hd), k_len=lengths + n_new)
     o = o.reshape(b, t, nh * hd)
-    return linear(o, p["wo"]), kc, vc
+    return linear(o, p["wo"], split="in"), kc, vc
 
 
 # ---------------------------------------------------------------------------
@@ -481,10 +485,10 @@ def mlp_block(p: Dict[str, Any], x: jax.Array, cfg: ModelConfig) -> jax.Array:
         gate, up = linear_group(x, (p["w_gate"], p["w_up"]),
                                 (None, None), cfg)
         h = maybe_shard(jax.nn.silu(gate) * up, "batch", None, "ff")
-        return linear(h, p["w_down"])
-    h = jax.nn.gelu(linear(x, p["w_up"], p.get("b_up")))
+        return linear(h, p["w_down"], split="in")
+    h = jax.nn.gelu(linear(x, p["w_up"], p.get("b_up"), split="out"))
     h = maybe_shard(h, "batch", None, "ff")
-    return linear(h, p["w_down"], p.get("b_down"))
+    return linear(h, p["w_down"], p.get("b_down"), split="in")
 
 
 # ---------------------------------------------------------------------------

@@ -4,7 +4,9 @@ from __future__ import annotations
 import dataclasses
 import json
 import logging
+import os
 import time
+from pathlib import Path
 from typing import Any
 
 import jax
@@ -17,6 +19,25 @@ if not logger.handlers:
     _h.setFormatter(logging.Formatter("[repro %(levelname)s] %(message)s"))
     logger.addHandler(_h)
     logger.setLevel(logging.INFO)
+
+
+# the checkout root (src/repro/utils.py -> ../..): caches the program writes
+# live at fixed, git-ignored paths under it, never under $HOME or a temp name
+REPO_ROOT = Path(__file__).resolve().parents[2]
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    `$JAX_COMPILATION_CACHE_DIR`, when set, is read by JAX itself and wins;
+    otherwise the cache lives at `<checkout>/.jax_cache`. The path must not
+    move between runs: it is part of what a later run looks up. Call before
+    the first compile."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          str(REPO_ROOT / ".jax_cache"))
+    jax.config.update("jax_enable_compilation_cache", True)
+    return jax.config.jax_compilation_cache_dir
 
 
 def round_up(x: int, multiple: int) -> int:

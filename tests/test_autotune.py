@@ -12,9 +12,9 @@ import pytest
 
 from repro.kernels import autotune, ops
 from repro.kernels.autotune import (AutotuneCache, VMEM_BUDGET,
-                                    candidate_blocks, flash_candidates,
-                                    flash_heuristic, heuristic_blocks,
-                                    normalize_key, paged_heuristic,
+                                    candidate_blocks, flash_heuristic,
+                                    heuristic_blocks, normalize_key,
+                                    paged_heuristic,
                                     pick_blocks, pick_flash_blocks,
                                     pick_paged_pad, vmem_bytes)
 
@@ -86,10 +86,9 @@ class TestCandidateGrid:
         for bm, _, _ in candidate_blocks(3, 4096, 4096, 4, "lut_fused_gemv"):
             assert bm == 8
 
-    def test_flash_candidates_divide_geometry(self):
-        for bq, bk in flash_candidates(512, 1024):
-            assert 512 % bq == 0 and 1024 % bk == 0
-        assert flash_candidates(512, 1024)[0] == flash_heuristic(512, 1024)
+    def test_flash_heuristic_clamps_to_geometry(self):
+        assert flash_heuristic(512, 1024) == (256, 512)
+        assert flash_heuristic(64, 128) == (64, 128)
 
 
 class TestInterpretFallback:
@@ -165,28 +164,46 @@ class TestMeasuredTuning:
         assert won != heur
 
     def test_all_candidates_failing_falls_back(self, cache):
+        # a grid the backend refuses outright is a kernel fault: it raises
+        # with the refusal chained, and nothing lands in the cache, rather
+        # than silently serving the heuristic
         def measure(*b):
             raise RuntimeError("no backend")
 
-        assert pick_blocks(8, 4096, 4096, interpret=False,
-                           measure=measure) == heuristic_blocks(8, 4096, 4096)
+        with pytest.raises(RuntimeError, match="candidates refused") as ei:
+            pick_blocks(8, 4096, 4096, interpret=False, measure=measure)
+        assert "no backend" in str(ei.value.__cause__)
+        assert cache.entries == {}
 
     def test_flash_and_paged_share_the_contract(self, cache):
-        calls = []
-        bq, bk = pick_flash_blocks(512, 1024, 64, interpret=False,
-                                   measure=lambda q, k: calls.append(1)
-                                   or float(k))
-        assert 512 % bq == 0 and 1024 % bk == 0 and calls
-        n = len(calls)
-        assert pick_flash_blocks(512, 1024, 64, interpret=False,
-                                 measure=lambda q, k: calls.append(1)
-                                 or float(k)) == (bq, bk)
-        assert len(calls) == n
-        assert pick_paged_pad(4, 64, 64, interpret=True) == \
+        # nothing measures the attention kernels: a cached winner on a
+        # compiled backend, else the heuristic; the interpreter always gets
+        # the heuristic
+        assert pick_flash_blocks(512, 1024, 64, interpret=False) == \
+            flash_heuristic(512, 1024)
+        assert pick_paged_pad(4, 512, 64, interpret=False) == \
+            paged_heuristic()[0]
+        backend = autotune.jax.default_backend()
+        cache.put(normalize_key(512, 1024, 64, 0, "flash", backend),
+                  (128, 256), 1.0)
+        cache.put(normalize_key(4, 512, 64, 8, "paged", backend), (256,),
+                  1.0)
+        assert pick_flash_blocks(512, 1024, 64, interpret=False) == (128, 256)
+        assert pick_paged_pad(4, 512, 64, interpret=False) == 256
+        assert pick_flash_blocks(512, 1024, 64, interpret=True) == \
+            flash_heuristic(512, 1024)
+        assert pick_paged_pad(4, 512, 64, interpret=True) == \
             paged_heuristic()[0]
 
 
 class TestPersistentCache:
+    def test_default_path_is_fixed_in_the_checkout(self, monkeypatch):
+        monkeypatch.delenv("REPRO_AUTOTUNE_CACHE", raising=False)
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert autotune.cache_path() == os.path.join(root, ".autotune.json")
+        monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", "/elsewhere/at.json")
+        assert autotune.cache_path() == "/elsewhere/at.json"
+
     def test_roundtrip_reload_hits_without_measuring(self, tmp_path):
         path = str(tmp_path / "autotune.json")
         c1 = autotune.reset_cache(path)

@@ -236,6 +236,33 @@ class TestBoundedTraces:
         assert set(eng.traces) == {1, ecfg.prefill_chunk}
         assert sum(eng.traces.values()) == 2
 
+    def test_decode_rows_never_ride_the_prefill_width(self, tiny):
+        """An iteration with one slot decoding and one prefilling runs the
+        prefill chunk at width prefill_chunk and the decode token at width
+        1, as two steps: a decode token is computed by the same program
+        whether or not a neighbour is prefilling (the two widths round
+        differently on TPU, DESIGN.md §5)."""
+        cfg, model, params = tiny
+        ecfg = EngineConfig(num_slots=2, block_size=4, num_blocks=16,
+                            max_blocks_per_slot=4, prefill_chunk=4)
+        eng = ServingEngine(model, params, ecfg)
+        a = eng.submit(_prompt(41, 3), 6)
+        eng.step()                      # a prefills (one chunk) and decodes
+        assert not a.prefilling
+        calls = []
+        run = eng._step_width
+        eng._step_width = lambda t, rids: (
+            calls.append((t, None if rids is None else set(rids)))
+            or run(t, rids))
+        b = eng.submit(_prompt(42, 9), 2)
+        steps = eng.steps
+        eng.step()
+        assert calls == [(ecfg.prefill_chunk, {b.rid}), (1, {a.rid})]
+        assert eng.steps == steps + 1
+        eng.run()
+        assert a.out_tokens == _solo_tokens(model, params, _prompt(41, 3), 6,
+                                            ecfg)
+
     def test_retrace_is_detected(self, tiny):
         cfg, model, params = tiny
         eng = ServingEngine(model, params, EngineConfig())

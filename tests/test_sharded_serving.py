@@ -13,8 +13,11 @@ import jax
 import numpy as np
 import pytest
 
+from repro.core.api import compress_model
+from repro.kernels.ops import lut_serving
+from repro.kernels.paged_attention import paged_attention_mode
 from repro.launch.engine import EngineConfig, ServingEngine, build_engine
-from repro.launch.mesh import make_elastic_mesh
+from repro.launch.mesh import auto_mesh, make_elastic_mesh
 from repro.models.config import ModelConfig
 from repro.models.registry import get_model
 
@@ -24,8 +27,7 @@ def _mesh(dp: int, mp: int):
     if len(devs) < dp * mp:
         pytest.skip(f"needs {dp * mp} devices, have {len(devs)} "
                     f"(run under `pytest -m mesh` / REPRO_MESH_LANE=1)")
-    return jax.make_mesh((dp, mp), ("data", "model"),
-                         devices=devs[:dp * mp])
+    return auto_mesh((dp, mp), ("data", "model"), devices=devs[:dp * mp])
 
 
 @pytest.fixture(scope="module")
@@ -132,6 +134,67 @@ class TestShardedParity:
                                   ecfg=ecfg)
         assert got == ref
         eng.assert_bounded_traces()
+
+
+@pytest.fixture(scope="module")
+def tiny_lcd(tiny):
+    cfg, model, params = tiny
+    return cfg, model, compress_model(params, target_centroids=4, nbits=2)[0]
+
+
+class TestKernelsPerDevice:
+    """LCD weights through the Pallas kernels (interpreted) on a mesh: a
+    Pallas call is one custom call GSPMD cannot partition, so each kernel
+    runs per device under shard_map — column-parallel projections on their
+    output columns, row-parallel ones on their K slice plus a psum, paged
+    attention replicated when the model axis does not divide the kv heads.
+    Tokens must equal the one-device engine's."""
+
+    @pytest.mark.mesh
+    @pytest.mark.parametrize("kv_dtype", ["float", "int8"])
+    def test_lcd_kernels_on_2x4_match_1x1(self, tiny_lcd, kv_dtype):
+        cfg, model, params = tiny_lcd
+        prompts = _prompts(vocab=cfg.vocab)
+        ecfg = _ecfg(kv_dtype=kv_dtype)
+        with lut_serving("interpret"), paged_attention_mode("interpret"):
+            ref, _ = _engine_tokens(model, params, _mesh(1, 1), prompts,
+                                    ecfg=ecfg)
+            got, eng = _engine_tokens(model, params, _mesh(2, 4), prompts,
+                                      ecfg=ecfg)
+        assert got == ref
+        wq = eng.params["blocks"]["attn"]["wq"].packed
+        assert "model" in str(wq.sharding.spec)
+
+    @pytest.mark.mesh
+    def test_kernel_operands_hold_only_local_slots(self, tiny_lcd,
+                                                   monkeypatch):
+        """On a 2x4 mesh each device's kernels see half the request slots:
+        data parallelism splits the work, it does not replicate it."""
+        import repro.kernels.ops as ops
+        import repro.kernels.paged_attention as pa
+        cfg, model, params = tiny_lcd
+        seen = {"lut": set(), "multi": set(), "attn": set()}
+
+        def spy(name, fn, arg):
+            def wrapped(*a, **kw):
+                seen[name].add(a[arg].shape[0])
+                return fn(*a, **kw)
+            return wrapped
+
+        monkeypatch.setattr(ops, "lut_gemm_fused",
+                            spy("lut", ops.lut_gemm_fused, 0))
+        monkeypatch.setattr(ops, "lut_gemm_fused_multi",
+                            spy("multi", ops.lut_gemm_fused_multi, 0))
+        monkeypatch.setattr(pa, "paged_pool_attention",
+                            spy("attn", pa.paged_pool_attention, 0))
+        ecfg = _ecfg()
+        with lut_serving("interpret"), paged_attention_mode("interpret"):
+            _engine_tokens(model, params, _mesh(2, 4), _prompts(vocab=cfg.vocab),
+                           gen=2, ecfg=ecfg)
+        local = ecfg.num_slots // 2
+        assert seen["attn"] == {local}
+        assert seen["lut"] | seen["multi"] == {
+            local * ecfg.prefill_chunk, local}
 
 
 class TestMeshKnobSurface:

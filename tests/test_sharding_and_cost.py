@@ -226,7 +226,8 @@ class TestSpecProperties:
         from repro.distributed.sharding import named_sharding
         rng = np.random.default_rng(1)
         for shape_dict in self.MESHES:
-            am = AbstractMesh(tuple(shape_dict.items()))
+            am = AbstractMesh(tuple(shape_dict.values()),
+                              tuple(shape_dict.keys()))
             sr = ShardingRules(am, dict(DEFAULT_RULES))
             for _ in range(50):
                 rank = int(rng.integers(1, 4))
@@ -261,7 +262,8 @@ class TestSpecProperties:
         def am_rules(shape):
             # NamedSharding construction needs a real(ish) mesh, so the
             # auto_shard sweep uses AbstractMesh instead of FakeMesh
-            return ShardingRules(AbstractMesh(tuple(shape.items())),
+            return ShardingRules(AbstractMesh(tuple(shape.values()),
+                                              tuple(shape.keys())),
                                  dict(DEFAULT_RULES))
 
         sr = am_rules({"data": 2, "model": 4})    # 48 % 4 == 0: ff shards
