@@ -85,6 +85,7 @@ Capability-typed cache protocols (DESIGN.md §13)
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import time
 import warnings
@@ -372,6 +373,20 @@ class BlockAllocator:
 QUEUED, RUNNING, FINISHED, CANCELLED = ("queued", "running", "finished",
                                         "cancelled")
 
+# the counters `prefix_cache_report` reads (BENCH_serving.json prefix_cache):
+# prompt tokens served from the cache, block grants satisfied by sharing and
+# by alloc, copy-on-write block copies, blocks published to the hash index
+PREFIX_CACHE_COUNTERS = ("cached_tokens", "shared_block_grants",
+                         "fresh_block_grants", "cow_copies",
+                         "registered_blocks")
+
+
+def _named(fn, name: str):
+    """`fn` renamed before jit, so its program is module `jit_<name>` in the
+    device trace (DESIGN.md §5 "Spans and counters")."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
 
 @dataclasses.dataclass
 class Request:
@@ -385,6 +400,9 @@ class Request:
     fed: int = 0                       # tokens of `feed` already in the cache
     preemptions: int = 0
     submit_t: float = 0.0
+    # first admission into a slot; a re-admission after preemption keeps it,
+    # so admit_t - submit_t is the wait before the request first ran
+    admit_t: Optional[float] = None
     first_token_t: Optional[float] = None
     finish_t: Optional[float] = None
     # multi-tenant scheduling (DESIGN.md §12): admission weight/budget key
@@ -678,14 +696,14 @@ class ServingEngine:
         # tenant — the weighted-fair share is served/weight
         self._tenant_inflight: Dict[str, int] = {}
         self._tenant_served: Dict[str, int] = {}
-        # prefix-cache telemetry (BENCH_serving.json prefix_cache section)
-        self.cache_stats: Dict[str, int] = {
-            "cached_tokens": 0,        # prompt tokens served from the cache
-            "shared_block_grants": 0,  # block grants satisfied by sharing
-            "fresh_block_grants": 0,   # block grants satisfied by alloc
-            "cow_copies": 0,           # copy-on-write block copies
-            "registered_blocks": 0,    # blocks published to the hash index
-        }
+        # cumulative counters (DESIGN.md §5 "Spans and counters"): the
+        # prefix-cache keys (PREFIX_CACHE_COUNTERS), scheduler counts, and
+        # per-width `calls.w<t>` / `rows.w<t>` / `rows_fed.w<t>` and
+        # per-phase `host_s.<phase>` keys added as they first occur
+        self.counters: Dict[str, float] = dict.fromkeys(
+            PREFIX_CACHE_COUNTERS + ("iterations", "tokens_emitted",
+                                     "admitted", "preempted"), 0)
+        self._phase_s = 0.0            # host seconds of every phase so far
         with use_rules(self.mesh, fsdp=False):
             # every cache the family declared, keyed by kind ("paged" block
             # pool and/or "slot" per-slot state, DESIGN.md §13)
@@ -867,6 +885,7 @@ class ServingEngine:
         # restored state; a decoding one keeps its pending token
         r.slot = None
         r.state, r.preemptions = QUEUED, r.preemptions + 1
+        self._count("preempted", 1)
         self.slots[s] = None
         self.lengths[s] = 0
         self.queue.appendleft(r)
@@ -935,18 +954,60 @@ class ServingEngine:
         }
 
     def prefix_cache_report(self) -> Dict[str, Any]:
-        """Prefix-cache telemetry (DESIGN.md §12): the cache_stats counters
+        """Prefix-cache telemetry (DESIGN.md §12): the prefix-cache counters
         plus the derived block-reuse rate (shared grants over all grants —
         the BENCH_serving.json `prefix_cache.cache_on.block_reuse_rate`
         headline) and the index's current size."""
-        out: Dict[str, Any] = dict(self.cache_stats)
+        out: Dict[str, Any] = {k: self.counters[k]
+                               for k in PREFIX_CACHE_COUNTERS}
         grants = out["shared_block_grants"] + out["fresh_block_grants"]
         out["block_reuse_rate"] = (
             round(out["shared_block_grants"] / grants, 4) if grants else 0.0)
         out["cached_blocks_now"] = self.alloc.num_cached
         return out
 
+    def counters_summary(self) -> Dict[str, Any]:
+        """The operator's digest of `counters` (the prefix-cache keys are
+        `prefix_cache_report`'s): scheduler iterations, program calls and
+        the share of computed rows (num_slots x width per call) that fed no
+        token per program width, host ms per iteration outside the device
+        wait, tokens emitted, admissions and preemptions."""
+        c = self.counters
+        widths = sorted(int(k[len("rows.w"):]) for k in c
+                        if k.startswith("rows.w"))
+        host_s = sum(v for k, v in c.items()
+                     if k.startswith("host_s.") and k != "host_s.wait")
+        return {
+            "iterations": c["iterations"],
+            "calls": {f"w{w}": c[f"calls.w{w}"] for w in widths},
+            "pad_row_share": {f"w{w}": round(
+                1.0 - c[f"rows_fed.w{w}"] / c[f"rows.w{w}"], 4)
+                for w in widths},
+            "host_ms_per_step": (round(1e3 * host_s / c["iterations"], 3)
+                                 if c["iterations"] else 0.0),
+            "tokens_emitted": c["tokens_emitted"],
+            "admitted": c["admitted"],
+            "preempted": c["preempted"],
+        }
+
     # -- scheduler ----------------------------------------------------------
+
+    def _count(self, key: str, n) -> None:
+        self.counters[key] = self.counters.get(key, 0) + n
+
+    @contextlib.contextmanager
+    def _phase(self, name: str, annotation=jax.profiler.TraceAnnotation,
+               **meta):
+        """Span `engine.<name>` on the profiler's clock (recorded only while
+        a profiler session is open) around one scheduler phase, and its
+        host self time (minus the phases nested in it) into
+        `counters["host_s.<name>"]`, on `self.clock`."""
+        t0, inner = self.clock(), self._phase_s
+        with annotation(f"engine.{name}", **meta):
+            yield
+        dt = self.clock() - t0
+        self._count(f"host_s.{name}", dt - (self._phase_s - inner))
+        self._phase_s = inner + dt
 
     def step(self) -> List[Request]:
         """One scheduler iteration: admit from the queue, advance every
@@ -967,22 +1028,27 @@ class ServingEngine:
         verify from the target. Steps with a prefilling slot keep the mixed
         prefill shape — decoding slots still advance one plain token there,
         through the combined step that feeds both caches."""
-        self._admit()
-        active = [(s, r) for s, r in enumerate(self.slots) if r is not None]
-        if not active:
-            return []
-        if self.spec_k and not any(r.prefilling for _, r in active):
-            return self._spec_round(active)
-        prefilling = {r.rid for _, r in active if r.prefilling}
-        if self.spec_k or len(prefilling) in (0, len(active)):
-            done = self._step_width(
-                self.ecfg.prefill_chunk if prefilling else 1, None)
-        else:
-            decoding = {r.rid for _, r in active} - prefilling
-            done = self._step_width(self.ecfg.prefill_chunk, prefilling)
-            done += self._step_width(1, decoding)
-        self.steps += 1
-        return done
+        with self._phase("step", jax.profiler.StepTraceAnnotation,
+                         step_num=self.steps):
+            self._count("iterations", 1)
+            with self._phase("admit"):
+                self._admit()
+            active = [(s, r) for s, r in enumerate(self.slots)
+                      if r is not None]
+            if not active:
+                return []
+            if self.spec_k and not any(r.prefilling for _, r in active):
+                return self._spec_round(active)
+            prefilling = {r.rid for _, r in active if r.prefilling}
+            if self.spec_k or len(prefilling) in (0, len(active)):
+                done = self._step_width(
+                    self.ecfg.prefill_chunk if prefilling else 1, None)
+            else:
+                decoding = {r.rid for _, r in active} - prefilling
+                done = self._step_width(self.ecfg.prefill_chunk, prefilling)
+                done += self._step_width(1, decoding)
+            self.steps += 1
+            return done
 
     def _step_width(self, t: int, rids) -> List[Request]:
         """One traced step of width `t` over the active slots whose request
@@ -998,45 +1064,52 @@ class ServingEngine:
         # here simply drops out of pass 2.
         def want(r):
             return min(len(r.feed) - r.fed, t) if r.prefilling else 1
-        if self.has_paged:
-            for s, r in active:
-                if self.slots[s] is not r:
-                    continue           # evicted by an earlier reservation
-                self._ensure_blocks(r, int(self.lengths[s]) + want(r))
+        with self._phase("reserve"):
+            if self.has_paged:
+                for s, r in active:
+                    if self.slots[s] is not r:
+                        continue       # evicted by an earlier reservation
+                    self._ensure_blocks(r, int(self.lengths[s]) + want(r))
 
-        # pass 1.5 — copy-on-write (DESIGN.md §12): a slot about to write
-        # into a block prefix caching granted read-only (refcount > 1) gets
-        # a private copy first. Like pass 1 this can evict, so it completes
-        # before any tokens are packed.
-        if ecfg.prefix_cache:
-            for s, r in enumerate(self.slots):
-                if r is None or (rids is not None and r.rid not in rids):
-                    continue
-                w = want(r)
-                if len(r.blocks) * ecfg.block_size >= int(self.lengths[s]) + w:
-                    self._cow_for_write(r, s, w)
+            # pass 1.5 — copy-on-write (DESIGN.md §12): a slot about to
+            # write into a block prefix caching granted read-only (refcount
+            # > 1) gets a private copy first. Like pass 1 this can evict, so
+            # it completes before any tokens are packed.
+            if ecfg.prefix_cache:
+                for s, r in enumerate(self.slots):
+                    if r is None or (rids is not None and r.rid not in rids):
+                        continue
+                    w = want(r)
+                    if (len(r.blocks) * ecfg.block_size
+                            >= int(self.lengths[s]) + w):
+                        self._cow_for_write(r, s, w)
 
         # pass 2 — pack the surviving slots into one traced batch (a slot
         # whose tail block is still shared — COW could not get a block —
         # waits this step, like starvation)
-        tokens = np.zeros((ecfg.num_slots, t), np.int32)
-        n_new = np.zeros(ecfg.num_slots, np.int32)
-        active = [(s, r) for s, r in enumerate(self.slots)
-                  if r is not None and (rids is None or r.rid in rids)]
-        for s, r in active:
-            w = want(r)
-            if self.has_paged and (
-                    len(r.blocks) * ecfg.block_size < int(self.lengths[s]) + w):
-                continue               # starved of blocks: waits this step
-            if self._write_shared(r, s, w):
-                continue               # COW starved: waits this step
-            if r.prefilling:
-                tokens[s, :w] = r.feed[r.fed:r.fed + w]
-            else:
-                tokens[s, 0] = r.out_tokens[-1]
-            n_new[s] = w
+        with self._phase("pack"):
+            tokens = np.zeros((ecfg.num_slots, t), np.int32)
+            n_new = np.zeros(ecfg.num_slots, np.int32)
+            active = [(s, r) for s, r in enumerate(self.slots)
+                      if r is not None and (rids is None or r.rid in rids)]
+            for s, r in active:
+                w = want(r)
+                if self.has_paged and (len(r.blocks) * ecfg.block_size
+                                       < int(self.lengths[s]) + w):
+                    continue           # starved of blocks: waits this step
+                if self._write_shared(r, s, w):
+                    continue           # COW starved: waits this step
+                if r.prefilling:
+                    tokens[s, :w] = r.feed[r.fed:r.fed + w]
+                else:
+                    tokens[s, 0] = r.out_tokens[-1]
+                n_new[s] = w
+            self._count(f"calls.w{t}", 1)
+            self._count(f"rows.w{t}", ecfg.num_slots * t)
+            self._count(f"rows_fed.w{t}", int(n_new.sum()))
 
-        with use_rules(self.mesh, fsdp=False):
+        with (self._phase("dispatch", width=t),
+              use_rules(self.mesh, fsdp=False)):
             if self.spec_k:
                 # combined step: the draft cache ingests the same tokens so
                 # it stays in lockstep with the target's accepted prefix
@@ -1050,23 +1123,25 @@ class ServingEngine:
                     self.params, self.caches, jnp.asarray(tokens),
                     jnp.asarray(self.lengths), jnp.asarray(n_new),
                     jnp.asarray(self.block_tables))
-        next_tok = np.asarray(next_tok)
+        with self._phase("wait", width=t):
+            next_tok = np.asarray(next_tok)
 
         done: List[Request] = []
-        for s, r in active:
-            if self.slots[s] is not r or not n_new[s]:
-                continue               # evicted by _ensure_blocks, or starved
-            r.fed += int(n_new[s])
-            self.lengths[s] += int(n_new[s])
-            if self.ecfg.prefix_cache:
-                self._register_blocks(s, r)
-            if not r.prefilling:       # last valid token's logits are usable
-                if r.first_token_t is None:
-                    r.first_token_t = self.clock()
-                self._emit(r, int(next_tok[s]))
-                if r.done:
-                    self._finish(r)
-                    done.append(r)
+        with self._phase("harvest"):
+            for s, r in active:
+                if self.slots[s] is not r or not n_new[s]:
+                    continue           # evicted by _ensure_blocks, or starved
+                r.fed += int(n_new[s])
+                self.lengths[s] += int(n_new[s])
+                if self.ecfg.prefix_cache:
+                    self._register_blocks(s, r)
+                if not r.prefilling:   # last valid token's logits are usable
+                    if r.first_token_t is None:
+                        r.first_token_t = self.clock()
+                    self._emit(r, int(next_tok[s]))
+                    if r.done:
+                        self._finish(r)
+                        done.append(r)
         return done
 
     # -- speculative round (DESIGN.md §8) ------------------------------------
@@ -1092,83 +1167,95 @@ class ServingEngine:
            the same way — both pools share block tables and `lengths`.
         """
         ecfg, k = self.ecfg, self.spec_k
-        for s, r in active:
-            if self.slots[s] is not r:
-                continue               # evicted by an earlier reservation
-            self._ensure_blocks(r, int(self.lengths[s]) + k + 1)
+        with self._phase("reserve", role="draft"):
+            for s, r in active:
+                if self.slots[s] is not r:
+                    continue           # evicted by an earlier reservation
+                self._ensure_blocks(r, int(self.lengths[s]) + k + 1)
 
-        # copy-on-write pass (DESIGN.md §12): a round writes the span
-        # [lengths, lengths + k + 1), so a shared tail block must be copied
-        # first; like reservations this can evict, so it runs to completion
-        # before participation is decided
-        if ecfg.prefix_cache:
+            # copy-on-write pass (DESIGN.md §12): a round writes the span
+            # [lengths, lengths + k + 1), so a shared tail block must be
+            # copied first; like reservations this can evict, so it runs to
+            # completion before participation is decided
+            if ecfg.prefix_cache:
+                for s, r in enumerate(self.slots):
+                    if r is None:
+                        continue
+                    if (len(r.blocks) * ecfg.block_size
+                            >= int(self.lengths[s]) + k + 1):
+                        self._cow_for_write(r, s, k + 1)
+
+            # participation is decided after ALL reservations: a reservation
+            # may have evicted a slot that reserved earlier
+            live: List[tuple] = []
             for s, r in enumerate(self.slots):
                 if r is None:
                     continue
-                if len(r.blocks) * ecfg.block_size >= int(self.lengths[s]) + k + 1:
-                    self._cow_for_write(r, s, k + 1)
-
-        # participation is decided after ALL reservations: a reservation may
-        # have evicted a slot that reserved earlier
-        live: List[tuple] = []
-        for s, r in enumerate(self.slots):
-            if r is None:
-                continue
-            if len(r.blocks) * ecfg.block_size >= int(self.lengths[s]) + k + 1:
-                assert r.out_tokens, "decoding slot must have a pending token"
-                if self._write_shared(r, s, k + 1):
-                    continue           # COW starved: waits this round
-                live.append((s, r))
+                if (len(r.blocks) * ecfg.block_size
+                        >= int(self.lengths[s]) + k + 1):
+                    assert r.out_tokens, (
+                        "decoding slot must have a pending token")
+                    if self._write_shared(r, s, k + 1):
+                        continue       # COW starved: waits this round
+                    live.append((s, r))
         if not live:
             self.steps += 1            # starved round: everyone waits
             return []
 
-        pend = np.zeros((ecfg.num_slots, 1), np.int32)
-        n_one = np.zeros(ecfg.num_slots, np.int32)
-        for s, r in live:
-            pend[s, 0] = r.out_tokens[-1]
-            n_one[s] = 1
+        with self._phase("pack", role="draft"):
+            pend = np.zeros((ecfg.num_slots, 1), np.int32)
+            n_one = np.zeros(ecfg.num_slots, np.int32)
+            for s, r in live:
+                pend[s, 0] = r.out_tokens[-1]
+                n_one[s] = 1
 
         with use_rules(self.mesh, fsdp=False):
-            drafts, self.draft_caches = self._draft_fn()(
-                self.draft_params, self.draft_caches, jnp.asarray(pend),
-                jnp.asarray(self.lengths), jnp.asarray(n_one),
-                jnp.asarray(self.block_tables))
-            drafts = np.asarray(drafts)                      # (S, k)
+            with self._phase("dispatch", role="draft"):
+                drafts, self.draft_caches = self._draft_fn()(
+                    self.draft_params, self.draft_caches, jnp.asarray(pend),
+                    jnp.asarray(self.lengths), jnp.asarray(n_one),
+                    jnp.asarray(self.block_tables))
+            with self._phase("wait", role="draft"):
+                drafts = np.asarray(drafts)                  # (S, k)
 
-            vtokens = np.zeros((ecfg.num_slots, k + 1), np.int32)
-            n_ver = np.zeros(ecfg.num_slots, np.int32)
-            for s, r in live:
-                vtokens[s, 0] = r.out_tokens[-1]
-                vtokens[s, 1:] = drafts[s]
-                n_ver[s] = k + 1
-            target, self.caches = self._verify_fn()(
-                self.params, self.caches, jnp.asarray(vtokens),
-                jnp.asarray(self.lengths), jnp.asarray(n_ver),
-                jnp.asarray(self.block_tables))
-        target = np.asarray(target)                          # (S, k+1)
+            with self._phase("pack", role="verify"):
+                vtokens = np.zeros((ecfg.num_slots, k + 1), np.int32)
+                n_ver = np.zeros(ecfg.num_slots, np.int32)
+                for s, r in live:
+                    vtokens[s, 0] = r.out_tokens[-1]
+                    vtokens[s, 1:] = drafts[s]
+                    n_ver[s] = k + 1
+            with self._phase("dispatch", role="verify", width=k + 1):
+                target, self.caches = self._verify_fn()(
+                    self.params, self.caches, jnp.asarray(vtokens),
+                    jnp.asarray(self.lengths), jnp.asarray(n_ver),
+                    jnp.asarray(self.block_tables))
+        with self._phase("wait", role="verify", width=k + 1):
+            target = np.asarray(target)                      # (S, k+1)
         self.steps += 1
         self.spec_rounds += 1
 
         done: List[Request] = []
-        for s, r in live:
-            accepted = 0
-            while accepted < k and target[s, accepted] == drafts[s, accepted]:
-                accepted += 1
-            emit = [int(t) for t in target[s, :accepted + 1]]
-            emit = emit[:r.max_new_tokens - len(r.out_tokens)]
-            # record the REALIZED advance (budget cap included), so the mean
-            # accepted length is the true target-dispatch multiplier
-            r.accept_lens.append(len(emit) - 1)
-            for tok in emit:
-                self._emit(r, tok)
-            # the rollback: only the emitted prefix becomes readable cache
-            self.lengths[s] += len(emit)
-            if self.ecfg.prefix_cache:
-                self._register_blocks(s, r)
-            if r.done:
-                self._finish(r)
-                done.append(r)
+        with self._phase("harvest", role="verify"):
+            for s, r in live:
+                accepted = 0
+                while (accepted < k
+                       and target[s, accepted] == drafts[s, accepted]):
+                    accepted += 1
+                emit = [int(t) for t in target[s, :accepted + 1]]
+                emit = emit[:r.max_new_tokens - len(r.out_tokens)]
+                # record the REALIZED advance (budget cap included), so the
+                # mean accepted length is the true target-dispatch multiplier
+                r.accept_lens.append(len(emit) - 1)
+                for tok in emit:
+                    self._emit(r, tok)
+                # the rollback: only the emitted prefix becomes readable cache
+                self.lengths[s] += len(emit)
+                if self.ecfg.prefix_cache:
+                    self._register_blocks(s, r)
+                if r.done:
+                    self._finish(r)
+                    done.append(r)
         return done
 
     # -- internals ----------------------------------------------------------
@@ -1177,7 +1264,6 @@ class ServingEngine:
         if t not in self._step_fns:
             model, cfg = self.model, self.model.cfg
 
-            @partial(jax.jit, donate_argnums=(1,))
             def step(params, caches, tokens, lengths, n_new, block_tables):
                 self.traces[t] = self.traces.get(t, 0) + 1   # trace-time only
                 logits, caches = model.serving_step(
@@ -1185,7 +1271,8 @@ class ServingEngine:
                 nxt = jnp.argmax(logits[..., :cfg.vocab], axis=-1)
                 return nxt.astype(jnp.int32), caches
 
-            self._step_fns[t] = step
+            self._step_fns[t] = jax.jit(_named(step, f"serve_step_w{t}"),
+                                        donate_argnums=(1,))
         return self._step_fns[t]
 
     def _spec_prefill_fn(self, t: int):
@@ -1198,7 +1285,6 @@ class ServingEngine:
         if key not in self._step_fns:
             model, cfg = self.model, self.model.cfg
 
-            @partial(jax.jit, donate_argnums=(2, 3))
             def step(params, dparams, caches, dcaches, tokens, lengths, n_new,
                      block_tables):
                 self.traces[key] = self.traces.get(key, 0) + 1
@@ -1209,7 +1295,8 @@ class ServingEngine:
                 nxt = jnp.argmax(logits[..., :cfg.vocab], axis=-1)
                 return nxt.astype(jnp.int32), caches, dcaches
 
-            self._step_fns[key] = step
+            self._step_fns[key] = jax.jit(
+                _named(step, f"serve_prefill_w{t}"), donate_argnums=(2, 3))
         return self._step_fns[key]
 
     def _draft_fn(self):
@@ -1229,7 +1316,6 @@ class ServingEngine:
         if key not in self._step_fns:
             model, cfg, k = self.model, self.model.cfg, self.spec_k
 
-            @partial(jax.jit, donate_argnums=(1,))
             def draft(dparams, dcaches, tok0, lengths, n_one, block_tables):
                 self.traces[key] = self.traces.get(key, 0) + 1
 
@@ -1245,7 +1331,8 @@ class ServingEngine:
                     body, (tok0, dcaches, lengths), None, length=k + 1)
                 return toks.swapaxes(0, 1)[:, :k], dcaches   # (S, k)
 
-            self._step_fns[key] = draft
+            self._step_fns[key] = jax.jit(_named(draft, f"serve_draft_k{k}"),
+                                          donate_argnums=(1,))
         return self._step_fns[key]
 
     def _verify_fn(self):
@@ -1256,7 +1343,6 @@ class ServingEngine:
         if key not in self._step_fns:
             model, cfg = self.model, self.model.cfg
 
-            @partial(jax.jit, donate_argnums=(1,))
             def verify(params, caches, tokens, lengths, n_new, block_tables):
                 self.traces[key] = self.traces.get(key, 0) + 1
                 logits, caches = model.serving_verify(
@@ -1264,7 +1350,9 @@ class ServingEngine:
                 nxt = jnp.argmax(logits[..., :cfg.vocab], axis=-1)
                 return nxt.astype(jnp.int32), caches
 
-            self._step_fns[key] = verify
+            self._step_fns[key] = jax.jit(
+                _named(verify, f"serve_verify_w{self.spec_k + 1}"),
+                donate_argnums=(1,))
         return self._step_fns[key]
 
     # -- per-slot state ops (SlotStateCache, DESIGN.md §13) ------------------
@@ -1282,21 +1370,23 @@ class ServingEngine:
                         self.traces.get("slot_reset", 0) + 1)
                     return jax.tree_util.tree_map(
                         lambda a: a.at[:, slot].set(0), state)
-                jitted = jax.jit(reset, donate_argnums=(0,))
+                jitted = jax.jit(_named(reset, "serve_slot_reset"),
+                                 donate_argnums=(0,))
             elif name == "snapshot":
                 # NOT donated: the engine state stays live for other slots
                 def take(state, slot):
                     self.traces["snapshot"] = (
                         self.traces.get("snapshot", 0) + 1)
                     return jax.tree_util.tree_map(lambda a: a[:, slot], state)
-                jitted = jax.jit(take)
+                jitted = jax.jit(_named(take, "serve_snapshot"))
             elif name == "restore":
                 def put(state, snap, slot):
                     self.traces["restore"] = self.traces.get("restore", 0) + 1
                     return jax.tree_util.tree_map(
                         lambda a, b: a.at[:, slot].set(b.astype(a.dtype)),
                         state, snap)
-                jitted = jax.jit(put, donate_argnums=(0,))
+                jitted = jax.jit(_named(put, "serve_restore"),
+                                 donate_argnums=(0,))
             else:                      # "encode": encoder prefill -> cross KV
                 def encode(params, state, frames, slot):
                     self.traces["encode"] = self.traces.get("encode", 0) + 1
@@ -1307,7 +1397,8 @@ class ServingEngine:
                     out["cv"] = state["cv"].at[:, slot].set(
                         cv.astype(state["cv"].dtype))
                     return out
-                jitted = jax.jit(encode, donate_argnums=(1,))
+                jitted = jax.jit(_named(encode, "serve_encode"),
+                                 donate_argnums=(1,))
             self._slot_fns[name] = jitted
         return self._slot_fns[name]
 
@@ -1366,6 +1457,7 @@ class ServingEngine:
                 self.block_tables[s] = 0
                 r.snapshot, r.snap_len = None, 0
                 self._tenant_acquire(r)
+                self._admitted(r)
                 continue
             feed = r.resume_feed()
             shared, hashes = ([], [])
@@ -1390,9 +1482,9 @@ class ServingEngine:
                     self.alloc.free(shared)  # undo the shares; r stays queued
                     return             # all-or-nothing: don't starve the pick
             self.queue.remove(r)
-            self.cache_stats["cached_tokens"] += cached_len
-            self.cache_stats["shared_block_grants"] += len(shared)
-            self.cache_stats["fresh_block_grants"] += len(blocks)
+            self._count("cached_tokens", cached_len)
+            self._count("shared_block_grants", len(shared))
+            self._count("fresh_block_grants", len(blocks))
             r.cached_tokens = cached_len
             r.hash_chain = hashes
             r.feed = feed
@@ -1407,6 +1499,12 @@ class ServingEngine:
                 if r.frames is not None:
                     self._slot_encode(s, r.frames)
             self._tenant_acquire(r)
+            self._admitted(r)
+
+    def _admitted(self, r: Request) -> None:
+        self._count("admitted", 1)
+        if r.admit_t is None:
+            r.admit_t = self.clock()
 
     # -- prefix cache, copy-on-write and tenant accounting (DESIGN.md §12) --
 
@@ -1448,7 +1546,7 @@ class ServingEngine:
             prev = r.hash_chain[-1] if r.hash_chain else self._prefix_salt
             h = self._chunk_hash(prev, stream[i * bs:(i + 1) * bs])
             if self.alloc.register(r.blocks[i], h):
-                self.cache_stats["registered_blocks"] += 1
+                self._count("registered_blocks", 1)
             r.hash_chain.append(h)
 
     def _touched_blocks(self, r: Request, s: int, w: int):
@@ -1497,7 +1595,7 @@ class ServingEngine:
             r.blocks[i] = new
             self.block_tables[s, i] = new
             self.alloc.free([old])     # drop our reference; sharers keep it
-            self.cache_stats["cow_copies"] += 1
+            self._count("cow_copies", 1)
         return True
 
     def _cow_copy_fn(self):
@@ -1513,7 +1611,8 @@ class ServingEngine:
                         out[name] = cache[name].at[:, dst].set(
                             cache[name][:, src])
                 return out
-            self._cow_fn = jax.jit(copy, donate_argnums=(0,))
+            self._cow_fn = jax.jit(_named(copy, "serve_cow"),
+                                   donate_argnums=(0,))
         return self._cow_fn
 
     def _pick_next(self) -> Optional[Request]:
@@ -1555,6 +1654,7 @@ class ServingEngine:
     def _emit(self, r: Request, tok: int) -> None:
         """Append one generated token: bookkeeping + streaming callback."""
         r.out_tokens.append(tok)
+        self._count("tokens_emitted", 1)
         self._tenant_served[r.tenant] = (
             self._tenant_served.get(r.tenant, 0) + 1)
         if r.on_token is not None:
@@ -1583,7 +1683,7 @@ class ServingEngine:
                 return False
 
     def _count_fresh(self, got: List[int]) -> List[int]:
-        self.cache_stats["fresh_block_grants"] += len(got)
+        self._count("fresh_block_grants", len(got))
         return got
 
     def _youngest_running(self, exclude: Request) -> Optional[Request]:
@@ -1602,6 +1702,7 @@ class ServingEngine:
         r.blocks, r.slot, r.fed, r.feed = [], None, 0, None
         r.hash_chain = []
         r.state, r.preemptions = QUEUED, r.preemptions + 1
+        self._count("preempted", 1)
         self.slots[s] = None
         self.lengths[s] = 0
         self.block_tables[s] = 0

@@ -131,6 +131,7 @@ def _run_continuous(args) -> None:
                     f"preemptions {r.preemptions})")
     logger.info(f"continuous engine: {len(finished)} requests in "
                 f"{engine.steps} steps, traces {engine.traces}")
+    logger.info(f"counters: {engine.counters_summary()}")
     if args.speculative:
         logger.info(f"speculative: {engine.acceptance_summary()}")
     if args.prefix_cache:

@@ -5,7 +5,6 @@ import dataclasses
 import json
 import logging
 import os
-import time
 from pathlib import Path
 from typing import Any
 
@@ -88,22 +87,6 @@ def asdict_shallow(cfg) -> dict:
     if dataclasses.is_dataclass(cfg):
         return {f.name: getattr(cfg, f.name) for f in dataclasses.fields(cfg)}
     return dict(cfg)
-
-
-class Timer:
-    """Context timer used by benchmarks (CPU wall-clock; TPU numbers come from
-    the roofline model, never from this)."""
-
-    def __init__(self, name: str = ""):
-        self.name = name
-        self.elapsed = 0.0
-
-    def __enter__(self):
-        self.t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        self.elapsed = time.perf_counter() - self.t0
 
 
 def stable_hash(obj: Any) -> int:

@@ -373,7 +373,7 @@ class TestPrefixCacheParity:
                                EngineConfig(**base, prefix_cache=True), specs,
                                stagger=50)
         assert on == off
-        assert eng.cache_stats["cow_copies"] >= 1
+        assert eng.counters["cow_copies"] >= 1
         assert "cow" in eng.traces         # COW compiled exactly once
         eng.assert_bounded_traces()
 
@@ -516,3 +516,119 @@ class TestSchedulerAndStreaming:
         assert not eng.cancel(running)      # idempotent: already terminal
         eng.run()
         assert not eng.busy
+
+
+class TestSpansAndCounters:
+    """The scheduler's own instrumentation (DESIGN.md §5 "Spans and
+    counters"): profiler spans per phase, cumulative counters, the
+    admission stamp and the step programs' stable names."""
+
+    PHASES = ["engine.admit", "engine.reserve", "engine.pack",
+              "engine.dispatch", "engine.wait", "engine.harvest"]
+
+    def test_phase_spans_nest_in_step_in_order(self, tiny, tmp_path):
+        import glob
+        cfg, model, params = tiny
+        ecfg = EngineConfig(num_slots=2, block_size=4, num_blocks=16,
+                            max_blocks_per_slot=4, prefill_chunk=4)
+        eng = ServingEngine(model, params, ecfg)
+        eng.submit(_prompt(51, 3), 4)
+        with jax.profiler.trace(str(tmp_path)):
+            for _ in range(3):          # prefill (width 4), then 2 decodes
+                eng.step()
+        path = glob.glob(f"{tmp_path}/plugins/profile/*/*.xplane.pb")[0]
+        pd = jax.profiler.ProfileData.from_file(path)
+        spans = sorted(((ev.start_ns, ev.start_ns + ev.duration_ns, ev.name,
+                         dict(ev.stats))
+                        for plane in pd.planes
+                        if plane.name.startswith("/host:")
+                        for line in plane.lines for ev in line.events
+                        if ev.name.startswith("engine.")),
+                       key=lambda e: (e[0], -e[1]))
+        steps = [e for e in spans if e[2] == "engine.step"]
+        assert [e[3]["step_num"] for e in steps] == [0, 1, 2]
+        for (s0, s1, _, _), width in zip(steps, (4, 1, 1)):
+            inner = [e for e in spans if e[2] != "engine.step"
+                     and s0 <= e[0] and e[1] <= s1]
+            assert [e[2] for e in inner] == self.PHASES
+            assert all(a[1] <= b[0] for a, b in zip(inner, inner[1:]))
+            assert [e[3]["width"] for e in inner
+                    if e[2] in ("engine.dispatch", "engine.wait")] == [
+                        width, width]
+        assert len(spans) == 3 * (1 + len(self.PHASES))
+
+    def test_counters_count_rows_tokens_and_host_time(self, tiny):
+        import itertools
+        cfg, model, params = tiny
+        ecfg = EngineConfig(num_slots=2, block_size=4, num_blocks=16,
+                            max_blocks_per_slot=4, prefill_chunk=4)
+        ticks = itertools.count()
+        eng = ServingEngine(model, params, ecfg,
+                            clock=lambda: float(next(ticks)))
+        reqs = [eng.submit(_prompt(61, 6), 3), eng.submit(_prompt(62, 2), 5)]
+        inside = 0.0
+        while eng.busy:
+            c0 = eng.clock()
+            eng.step()
+            inside += eng.clock() - c0 - 2   # minus the two reads around
+        c = eng.counters
+        assert c["tokens_emitted"] == sum(len(r.out_tokens) for r in reqs)
+        assert c["admitted"] == 2 and c["preempted"] == 0
+        assert c["iterations"] == eng.steps
+        # prompt tokens through the wide program, one token per decode
+        assert c["rows_fed.w4"] == 6 + 2
+        assert c["rows_fed.w1"] == sum(len(r.out_tokens) - 1 for r in reqs)
+        for w in (1, 4):
+            assert c[f"rows.w{w}"] == c[f"calls.w{w}"] * ecfg.num_slots * w
+        host = {k: v for k, v in c.items() if k.startswith("host_s.")}
+        assert set(host) == {"host_s.step"} | {
+            f"host_s.{p.split('.')[1]}" for p in self.PHASES}
+        # self times: they partition the time inside step()
+        assert min(host.values()) >= 0 and sum(host.values()) == inside
+        summary = eng.counters_summary()
+        assert summary["pad_row_share"]["w4"] == round(
+            1 - 8 / c["rows.w4"], 4)
+        assert summary["host_ms_per_step"] == round(
+            1e3 * (inside - host["host_s.wait"]) / eng.steps, 3)
+        assert summary["calls"] == {"w1": c["calls.w1"],
+                                    "w4": c["calls.w4"]}
+        assert (summary["iterations"], summary["tokens_emitted"],
+                summary["admitted"], summary["preempted"]) == (
+                    eng.steps, c["tokens_emitted"], 2, 0)
+        assert eng.prefix_cache_report() == {
+            "cached_tokens": 0, "shared_block_grants": 0,
+            "fresh_block_grants": c["fresh_block_grants"], "cow_copies": 0,
+            "registered_blocks": 0, "block_reuse_rate": 0.0,
+            "cached_blocks_now": 0}
+
+    def test_admit_t_is_the_first_admission(self, tiny):
+        cfg, model, params = tiny
+        ecfg = EngineConfig(num_slots=2, block_size=2, num_blocks=8,
+                            max_blocks_per_slot=8, prefill_chunk=4)
+        eng = ServingEngine(model, params, ecfg)
+        r1 = eng.submit(_prompt(41, 4), 10)    # the preemption test's pair:
+        r2 = eng.submit(_prompt(42, 4), 10)    # both cannot fit the pool
+        assert r1.admit_t is None
+        eng.step()
+        first = {r.rid: r.admit_t for r in (r1, r2)}
+        eng.run()
+        preempted = [r for r in (r1, r2) if r.preemptions]
+        assert preempted
+        for r in (r1, r2):
+            assert r.admit_t == first[r.rid]
+            assert r.submit_t <= r.admit_t <= r.first_token_t
+        n = sum(r.preemptions for r in (r1, r2))
+        assert eng.counters["preempted"] == n
+        assert eng.counters["admitted"] == 2 + n
+
+    def test_step_programs_carry_stable_names(self, tiny):
+        cfg, model, params = tiny
+        ecfg = EngineConfig(num_slots=2, block_size=4, num_blocks=16,
+                            max_blocks_per_slot=4, prefill_chunk=8)
+        eng = ServingEngine(model, params, ecfg)
+        for t in (1, ecfg.prefill_chunk):
+            text = eng._step_fn(t).lower(
+                eng.params, eng.caches, jnp.zeros((2, t), jnp.int32),
+                jnp.asarray(eng.lengths), jnp.zeros(2, jnp.int32),
+                jnp.asarray(eng.block_tables)).as_text()
+            assert f"module @jit_serve_step_w{t} " in text
