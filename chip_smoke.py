@@ -39,9 +39,10 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "src"))
 
 ARCH = "qwen2-1.5b"
-# 4 slots x 32 blocks of 16 tokens: a 512-token window per request, with
-# room for all 4 slots at the longest prompt; prefill in 128-token chunks
-GEOMETRY = dict(num_slots=4, block_size=16, num_blocks=128,
+# 16 slots (the chat cell's) x 32 blocks of 16 tokens: a 512-token window
+# per request, with room for every slot at the longest prompt; prefill in
+# 128-token chunks, so the wide step runs the chat cell's 2048 rows
+GEOMETRY = dict(num_slots=16, block_size=16, num_blocks=512,
                 max_blocks_per_slot=32, prefill_chunk=128)
 N_REQUESTS, PROMPT_LENS, NEW_TOKENS = 6, (192, 384), 32
 TP_LAYERS = 2
@@ -238,8 +239,10 @@ def one_chip(args) -> None:
     launches, t_warm = warm_up(engine)
     log(f"warm-up: {t_warm!r} s, traces {engine.traces}")
     log(f"lut launches per layer (both widths): {list(launches)}")
-    for (variant, m, k, n), blocks in sorted(launches.blocks.items()):
-        log(f"  blocks {variant} m={m} k={k} n={n}: (bm, bn, bk)={blocks}")
+    for key, blocks in sorted(launches.blocks.items()):
+        variant, m, k, n = key
+        log(f"  blocks {variant} m={m} k={k} n={n}: (bm, bn, bk)={blocks}, "
+            f"rows_per_decode={launches.rows_per_decode[key]}")
     if not any(tag.startswith("fused") for tag in launches):
         raise SystemExit("no fused LUT launch was traced")
 

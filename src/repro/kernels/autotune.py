@@ -62,9 +62,11 @@ VMEM_BUDGET = 8 * 1024 * 1024  # the working-set bound _pick_blocks was sized to
 
 # v2: the fused multi-projection variants (lut_fused_multi[_gemv]) key their
 # own entries and the VMEM formula became P-aware (`n_ops`); v1 entries could
-# alias a multi call onto a single-projection winner that blows the budget,
-# so old caches are discarded wholesale rather than migrated.
-CACHE_SCHEMA_VERSION = 2
+# alias a multi call onto a single-projection winner that blows the budget.
+# v3: the fused GEMMs decode each weight tile once per M chunk (`m_chunk`),
+# so a winner timed under the per-M-block layout is stale. Old caches are
+# discarded wholesale rather than migrated.
+CACHE_SCHEMA_VERSION = 3
 _ENV_CACHE = "REPRO_AUTOTUNE_CACHE"
 _ENV_ENABLE = "REPRO_AUTOTUNE"
 
@@ -76,8 +78,9 @@ GEMV_VARIANTS = ("lut_fused_gemv", "lut_fused_multi_gemv")
 
 
 def heuristic_blocks(m: int, k: int, n: int) -> Tuple[int, int, int]:
-    """MXU-aligned blocks sized to keep the VMEM working set under ~8 MiB:
-    bm*bk*4 + bk*bn*nbits/8 + bm*bn*4 bytes.
+    """MXU-aligned blocks whose working set at one M block (`vmem_bytes`)
+    stays well under VMEM_BUDGET, which leaves the fused GEMM room for a
+    chunk of many M blocks (`m_chunk`).
 
     GEMV-aware: decode-shaped calls (m < 128) collapse M into one
     sublane-aligned block (multiple of 8 for f32) consumed by the N-major
@@ -89,17 +92,39 @@ def heuristic_blocks(m: int, k: int, n: int) -> Tuple[int, int, int]:
 
 
 def vmem_bytes(bm: int, bn: int, bk: int, nbits: int = 4,
-               n_ops: int = 1) -> int:
-    """Working-set bytes of one LUT-matmul grid step: f32 x tile + packed
-    code tile + f32 accumulator (the budget formula of `heuristic_blocks`,
-    generalized over the packing width).
+               n_ops: int = 1, rows: int = None) -> int:
+    """VMEM working-set bytes of one LUT-matmul launch. `rows` is the M rows
+    resident at once: the fused GEMM's `m_chunk`, else (and by default) bm.
 
-    `n_ops` is the projection count of a fused multi call
-    (lut_matmul_fused_multi): every projection's current packed tile stays
-    resident in VMEM simultaneously — Pallas holds one block per operand —
-    so the code-tile term scales with P even though only one tile is read
-    per grid step."""
-    return bm * bk * 4 + n_ops * (bk * bn * nbits // 8) + bm * bn * 4
+      f32 accumulator           rows x bn x 4
+      output block, two buffers rows x bn x 4 x 2
+      x block, two buffers      bm x bk x 4 x 2  (counted at f32)
+      decoded f32 weight tile   bk x bn x 4
+      packed code tiles         n_ops x bk x bn x nbits/8 x 2
+
+    Pallas double-buffers every blocked operand. `n_ops` is the projection
+    count of a fused multi call (lut_matmul_fused_multi): every
+    projection's current packed tile stays resident — Pallas holds one
+    block per operand — so that term scales with P even though one tile is
+    read per grid step."""
+    rows = rows or bm
+    return (rows * bn * 4 * 3 + bm * bk * 4 * 2 + bk * bn * 4
+            + n_ops * (bk * bn * nbits // 8) * 2)
+
+
+def m_chunk(m: int, bm: int, bn: int, bk: int, nbits: int = 4,
+            n_ops: int = 1) -> int:
+    """Rows of a fused GEMM launch that share one decoded weight tile: the
+    largest whole number of bm-blocks that divides M (already padded to a
+    bm multiple) and whose working set (`vmem_bytes` at rows=m_chunk) fits
+    VMEM_BUDGET; bm when no larger chunk does. The kernel then decodes
+    every tile M / m_chunk times per launch."""
+    nblk = m // bm
+    for c in range(nblk, 1, -1):
+        if nblk % c == 0 and vmem_bytes(bm, bn, bk, nbits, n_ops,
+                                        rows=c * bm) <= VMEM_BUDGET:
+            return c * bm
+    return bm
 
 
 def candidate_blocks(m: int, k: int, n: int, nbits: int = 4,
@@ -108,9 +133,10 @@ def candidate_blocks(m: int, k: int, n: int, nbits: int = 4,
     """The measured grid: MXU-aligned (bm, bn, bk) triples that (a) never pad
     the problem beyond one block of slack, (b) cover whole packing groups
     (bk·nbits ≡ 0 mod 8), and (c) fit the VMEM budget — P-aware for the
-    fused multi variants (`n_ops` resident code tiles). The heuristic's
-    choice is always first, so the tuner's argmin can only match or beat
-    it."""
+    fused multi variants (`n_ops` resident code tiles) at one M block of
+    rows, the least `m_chunk` gives, so every candidate runs and the GEMM
+    picks its own chunk for it. The heuristic's choice is always first, so
+    the tuner's argmin can only match or beat it."""
     heur = heuristic_blocks(m, k, n)
     if variant in GEMV_VARIANTS or m < 128:
         bms: Sequence[int] = (round_up(m, 8),)  # one resident M block

@@ -30,6 +30,9 @@ Four entry points:
                     smoothed/quantized activation tile lives only in VMEM and
                     never round-trips HBM (the seed ran smooth-divide,
                     smooth_quant and the LUT GEMM as three HBM-bound passes).
+                    M runs innermost in its grid, so each weight tile is
+                    decoded once per VMEM-sized chunk of rows and shared by
+                    every 128-row M block of it.
   lut_matmul_fused_gemv — decode specialization of the fused kernel for
                     M < 128 (auto-regressive GEMV): the M grid dimension is
                     collapsed into a single sublane-aligned block and the grid
@@ -39,7 +42,7 @@ Four entry points:
                     while the MXU consumes the previous tile.
 
 Block shapes default to MXU-aligned (128 multiples); the K (=d_in) dimension is
-streamed so the VMEM working set is  bm*bk (x) + bk*bn/2 (codes) + bm*bn (acc).
+streamed, and `autotune.vmem_bytes` counts the VMEM working set a launch holds.
 """
 from __future__ import annotations
 
@@ -270,31 +273,94 @@ def _transform_tile(x_ref, inv_ref, quantize: bool):
     return xs
 
 
-def _fused_kernel(x_ref, inv_ref, packed_ref, cb_ref, o_ref, acc_ref, *,
-                  bk: int, bn: int, nsteps: int, quantize: bool, k_axis: int,
-                  nbits: int):
-    """One body for both fused variants; K is grid axis `k_axis` (innermost)
-    so acc_ref carries partials. GEMM: grid (M/bm, N/bn, K/bk), k_axis=2.
-    GEMV: grid (N/bn, K/bk), k_axis=1."""
-    ks = pl.program_id(k_axis)
+def _grid_pos(gemm: bool, bm: int):
+    """(N block j, K step, M block i of the chunk, accumulator rows of
+    block i) of the current grid step.
+
+    GEMV grid (N/bn, K/bk): one resident M block owns the whole accumulator.
+    GEMM grid (M/m_chunk, N/bn, K/bk, m_chunk/bm): M innermost, so the
+    accumulator and the output block hold a chunk of m_chunk rows, and
+    block i owns its rows i*bm .. i*bm + bm."""
+    if not gemm:
+        return pl.program_id(0), pl.program_id(1), 0, slice(None)
+    i = pl.program_id(3)
+    return (pl.program_id(1), pl.program_id(2), i,
+            pl.ds(pl.multiple_of(i * bm, bm), bm))
+
+
+def _decoded_tile(decode, w_ref, i):
+    """The grid step's (bk, bn) f32 weight tile. Without the scratch `w_ref`
+    (a GEMV, or a GEMM chunk of one M block) it is decoded in place. With
+    it, it is decoded into `w_ref` at the chunk's first M block and read
+    back by every M block of the chunk, so a launch decodes each tile once
+    per chunk, not once per bm rows."""
+    if w_ref is None:
+        return decode()
+
+    @pl.when(i == 0)
+    def _decode():
+        w_ref[...] = decode()
+
+    return w_ref[...]
+
+
+def _fused_kernel(x_ref, inv_ref, packed_ref, cb_ref, o_ref, acc_ref,
+                  w_ref=None, *, gemm: bool, bm: int, bk: int, bn: int,
+                  nsteps: int, quantize: bool, nbits: int):
+    """One body for both fused variants (grids in `_grid_pos`); a GEMM with
+    more than one M block per chunk passes the decoded-tile scratch `w_ref`
+    (`_gemm_layout`). K advances outside M, so each M block's accumulator
+    rows carry its partials across the K steps. Every output row sees the
+    same f32 op sequence in both layouts and at any chunk size:
+    acc += dot(transform(x), w) over the K steps in order, at one
+    (bm, bk) x (bk, bn) shape."""
+    _, ks, i, rows = _grid_pos(gemm, bm)
 
     @pl.when(ks == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        acc_ref[rows, :] = jnp.zeros((bm, bn), jnp.float32)
 
     xs = _transform_tile(x_ref, inv_ref, quantize)
-    w = _decode_tile(packed_ref, cb_ref[...], bk, bn, jnp.float32, nbits)
-    acc_ref[...] += jnp.dot(xs, w, preferred_element_type=jnp.float32)
+    w = _decoded_tile(lambda: _decode_tile(packed_ref, cb_ref[...], bk, bn,
+                                           jnp.float32, nbits), w_ref, i)
+    acc_ref[rows, :] += jnp.dot(xs, w, preferred_element_type=jnp.float32)
 
     @pl.when(ks == nsteps - 1)
     def _done():
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+        o_ref[rows, :] = acc_ref[rows, :].astype(o_ref.dtype)
+
+
+def _check_chunk(m: int, bm: int, m_chunk: int) -> None:
+    if m_chunk % bm or m % m_chunk:
+        raise ValueError(
+            f"m_chunk={m_chunk} must be a multiple of bm={bm} that divides "
+            f"M={m}")
+
+
+def _gemm_layout(m: int, n: int, k: int, bm: int, bn: int, bk: int,
+                 m_chunk: int):
+    """Grid, activation and output BlockSpecs and scratch of the GEMM
+    variants (axes (c, j, s, i) as in `_grid_pos`): an x block of bm rows
+    per grid step, an output block and f32 accumulator of m_chunk rows that
+    stay resident across the chunk's K steps and M blocks, and, when the
+    chunk holds more than one M block, the (bk, bn) decoded-tile scratch
+    they share. A chunk of one block (m_chunk == bm) is the per-M-block
+    layout: grid (M/bm, N/bn, K/bk, 1), each tile decoded in place."""
+    _check_chunk(m, bm, m_chunk)
+    nblk = m_chunk // bm
+    grid = (m // m_chunk, n // bn, k // bk, nblk)
+    x_spec = pl.BlockSpec((bm, bk), lambda c, j, s, i: (c * nblk + i, s))
+    out_spec = pl.BlockSpec((m_chunk, bn), lambda c, j, s, i: (c, j))
+    scratch = [pltpu.VMEM((m_chunk, bn), jnp.float32)]
+    if nblk > 1:
+        scratch.append(pltpu.VMEM((bk, bn), jnp.float32))
+    return grid, x_spec, out_spec, scratch
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("quantize", "bm", "bn", "bk", "interpret", "out_dtype",
-                     "nbits")
+                     "nbits", "m_chunk")
 )
 def lut_matmul_fused(
     x: jax.Array,            # (M, K) float — RAW activations (not smoothed)
@@ -309,8 +375,16 @@ def lut_matmul_fused(
     interpret: bool = False,
     out_dtype=jnp.float32,
     nbits: int = 4,
+    m_chunk: int = None,     # rows sharing one decoded tile; None -> bm
 ) -> jax.Array:
     """Y = transform(x) @ codebook[codes], transform fused into every K-step.
+
+    Grid (M/m_chunk, N/bn, K/bk, m_chunk/bm), M innermost: each (bk, bn)
+    weight tile is decoded once per chunk of m_chunk rows into VMEM and
+    every bm-row block of the chunk multiplies against it, so a wide call
+    decodes the weights M/m_chunk times, not M/bm times (`_grid_pos`). The
+    ops.py wrapper passes the chunk the VMEM budget allows
+    (`autotune.m_chunk`).
 
     The caller applies the trailing s_q rescale (quantize=True); XLA fuses that
     scalar multiply into the output copy, so the pipeline is one kernel + one
@@ -328,23 +402,24 @@ def lut_matmul_fused(
                          f"{codebook.shape}")
     _check_blocks(m, k, n, bm, bk, bn, nbits, "lut_matmul_fused")
     nsteps = k // bk
-    grid = (m // bm, n // bn, nsteps)
     kernel = functools.partial(
-        _fused_kernel, bk=bk, bn=bn, nsteps=nsteps, quantize=quantize,
-        k_axis=2, nbits=nbits,
+        _fused_kernel, gemm=True, bm=bm, bk=bk, bn=bn, nsteps=nsteps,
+        quantize=quantize, nbits=nbits,
     )
+    grid, x_spec, out_spec, scratch = _gemm_layout(m, n, k, bm, bn, bk,
+                                                   m_chunk or bm)
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, s: (i, s)),
-            pl.BlockSpec((1, bk), lambda i, j, s: (0, s)),
-            pl.BlockSpec((bk * nbits // 8, bn), lambda i, j, s: (s, j)),
-            pl.BlockSpec((KC,), lambda i, j, s: (0,)),
+            x_spec,
+            pl.BlockSpec((1, bk), lambda c, j, s, i: (0, s)),
+            pl.BlockSpec((bk * nbits // 8, bn), lambda c, j, s, i: (s, j)),
+            pl.BlockSpec((KC,), lambda c, j, s, i: (0,)),
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, s: (i, j)),
+        out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        scratch_shapes=scratch,
         interpret=interpret,
     )(x, inv_scale[None, :], packed_codes, codebook)
 
@@ -378,7 +453,7 @@ def lut_matmul_fused_gemv(
     decode+FMA) — the memory-bound regime where sub-byte codes buy the
     paper's 6.2x, and where a 2-bit tensor streams HALF the bytes per token
     of the int4 layout (DESIGN.md §10). Same kernel body as the GEMM variant
-    (k_axis selects the grid axis), so the two stay numerically locked
+    (without the decoded-tile scratch), so the two stay numerically locked
     together.
     """
     m, k = x.shape
@@ -400,8 +475,8 @@ def lut_matmul_fused_gemv(
     nsteps = k // bk
     grid = (n // bn, nsteps)
     kernel = functools.partial(
-        _fused_kernel, bk=bk, bn=bn, nsteps=nsteps, quantize=quantize,
-        k_axis=1, nbits=nbits,
+        _fused_kernel, gemm=False, bm=bm, bk=bk, bn=bn, nsteps=nsteps,
+        quantize=quantize, nbits=nbits,
     )
     return pl.pallas_call(
         kernel,
@@ -423,8 +498,8 @@ def lut_matmul_fused_gemv(
 # Fused MULTI-projection serving GEMM/GEMV (QKV, gate+up share one input)
 # ---------------------------------------------------------------------------
 
-def _fused_multi_kernel(x_ref, inv_ref, cb_ref, *rest, bk: int, bn: int,
-                        nsteps: int, quantize, k_axis: int, nbits,
+def _fused_multi_kernel(x_ref, inv_ref, cb_ref, *rest, gemm: bool, bm: int,
+                        bk: int, bn: int, nsteps: int, quantize, nbits,
                         bounds):
     """One body for both fused multi variants: P projections sharing the
     activation tile, concatenated along N. `bounds[p] = (s0, nblk)` is
@@ -443,16 +518,20 @@ def _fused_multi_kernel(x_ref, inv_ref, cb_ref, *rest, bk: int, bn: int,
     `quantize` and `nbits` are per-projection tuples: a mixed-precision
     layer (wq at 4-bit, wk/wv demoted to 2-bit) still fuses into ONE kernel
     launch — each packed operand unpacks at its own static width.
+
+    A GEMM with more than one M block per chunk passes the decoded-tile
+    scratch after the accumulator, and decodes only the live projection's
+    tile, once per M chunk, as `_fused_kernel` does.
     """
     n_proj = len(bounds)
     packed_refs = rest[:n_proj]
-    o_ref, acc_ref = rest[n_proj], rest[n_proj + 1]
-    j = pl.program_id(k_axis - 1)
-    ks = pl.program_id(k_axis)
+    o_ref, acc_ref, *w_ref = rest[n_proj:]
+    w_ref = w_ref[0] if w_ref else None
+    j, ks, i, rows = _grid_pos(gemm, bm)
 
     @pl.when(ks == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        acc_ref[rows, :] = jnp.zeros((bm, bn), jnp.float32)
 
     x = x_ref[...].astype(jnp.float32)
     inv = inv_ref[...].astype(jnp.float32)                # (P, bk)
@@ -463,14 +542,15 @@ def _fused_multi_kernel(x_ref, inv_ref, cb_ref, *rest, bk: int, bn: int,
             xs = x * inv[p][None, :]
             if quantize[p]:
                 xs = jnp.clip(jnp.round(xs), -127.0, 127.0)
-            w = _decode_tile(packed_refs[p], cb[p], bk, bn, jnp.float32,
-                             nbits[p])
-            acc_ref[...] += jnp.dot(xs, w,
-                                    preferred_element_type=jnp.float32)
+            w = _decoded_tile(
+                lambda: _decode_tile(packed_refs[p], cb[p], bk, bn,
+                                     jnp.float32, nbits[p]), w_ref, i)
+            acc_ref[rows, :] += jnp.dot(xs, w,
+                                        preferred_element_type=jnp.float32)
 
     @pl.when(ks == nsteps - 1)
     def _done():
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+        o_ref[rows, :] = acc_ref[rows, :].astype(o_ref.dtype)
 
 
 def _multi_segments(widths, bn: int):
@@ -527,7 +607,7 @@ def _packed_multi_spec(s0: int, nblk: int, rows: int, bn: int, gemv: bool):
             live = (j >= s0) & (j < s0 + nblk)
             return (jnp.where(live, s, 0), jnp.clip(j - s0, 0, nblk - 1))
     else:
-        def imap(i, j, s):
+        def imap(c, j, s, i):
             live = (j >= s0) & (j < s0 + nblk)
             return (jnp.where(live, s, 0), jnp.clip(j - s0, 0, nblk - 1))
     return pl.BlockSpec((rows, bn), imap)
@@ -536,7 +616,7 @@ def _packed_multi_spec(s0: int, nblk: int, rows: int, bn: int, gemv: bool):
 @functools.partial(
     jax.jit,
     static_argnames=("widths", "quantize", "bm", "bn", "bk", "interpret",
-                     "out_dtype", "nbits"))
+                     "out_dtype", "nbits", "m_chunk"))
 def lut_matmul_fused_multi(
     x: jax.Array,            # (M, K) RAW activations shared by all projections
     inv_stack: jax.Array,    # (P, K) f32 — per-projection Eq. 11 multipliers
@@ -550,14 +630,17 @@ def lut_matmul_fused_multi(
     interpret: bool = False,
     out_dtype=jnp.float32,
     nbits: tuple = (4,),     # per-projection packing width
+    m_chunk: int = None,     # rows sharing one decoded tile; None -> bm
 ) -> jax.Array:
     """Y = concat_p(transform_p(x) @ codebook_p[codes_p]) in ONE kernel.
 
     The activation tile is read (and smoothed/quantized) once per K-step and
     reused by whichever projection owns the current N segment — one kernel
     launch and one activation stream replace P of each, which is the entire
-    win for decode QKV / gate+up (DESIGN.md §15). Returns (M, Σ widths);
-    the ops.py wrapper splits segments and applies per-projection act_scale.
+    win for decode QKV / gate+up (DESIGN.md §15). The grid is
+    `lut_matmul_fused`'s: each live tile is decoded once per m_chunk rows.
+    Returns (M, Σ widths); the ops.py wrapper splits segments and applies
+    per-projection act_scale.
     """
     m, k = x.shape
     n = sum(widths)
@@ -570,14 +653,15 @@ def lut_matmul_fused_multi(
                  nbits, bm, bn, bk, "lut_matmul_fused_multi")
     nsteps = k // bk
     bounds = _multi_segments(widths, bn)
-    grid = (m // bm, n // bn, nsteps)
     kernel = functools.partial(
-        _fused_multi_kernel, bk=bk, bn=bn, nsteps=nsteps, quantize=quantize,
-        k_axis=2, nbits=nbits, bounds=bounds)
+        _fused_multi_kernel, gemm=True, bm=bm, bk=bk, bn=bn, nsteps=nsteps,
+        quantize=quantize, nbits=nbits, bounds=bounds)
+    grid, x_spec, out_spec, scratch = _gemm_layout(m, n, k, bm, bn, bk,
+                                                   m_chunk or bm)
     in_specs = [
-        pl.BlockSpec((bm, bk), lambda i, j, s: (i, s)),
-        pl.BlockSpec((len(widths), bk), lambda i, j, s: (0, s)),
-        pl.BlockSpec((len(widths), KC), lambda i, j, s: (0, 0)),
+        x_spec,
+        pl.BlockSpec((len(widths), bk), lambda c, j, s, i: (0, s)),
+        pl.BlockSpec((len(widths), KC), lambda c, j, s, i: (0, 0)),
     ] + [
         _packed_multi_spec(s0, nblk, bk * nbits[p] // 8, bn, gemv=False)
         for p, (s0, nblk) in enumerate(bounds)
@@ -586,9 +670,9 @@ def lut_matmul_fused_multi(
         kernel,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, s: (i, j)),
+        out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        scratch_shapes=scratch,
         interpret=interpret,
     )(x, inv_stack, cb_stack, *packed_list)
 
@@ -635,8 +719,8 @@ def lut_matmul_fused_multi_gemv(
     bounds = _multi_segments(widths, bn)
     grid = (n // bn, nsteps)
     kernel = functools.partial(
-        _fused_multi_kernel, bk=bk, bn=bn, nsteps=nsteps, quantize=quantize,
-        k_axis=1, nbits=nbits, bounds=bounds)
+        _fused_multi_kernel, gemm=False, bm=bm, bk=bk, bn=bn, nsteps=nsteps,
+        quantize=quantize, nbits=nbits, bounds=bounds)
     in_specs = [
         pl.BlockSpec((bm, bk), lambda j, s: (0, s)),
         pl.BlockSpec((len(widths), bk), lambda j, s: (0, s)),

@@ -38,13 +38,26 @@ _pick_blocks = autotune.heuristic_blocks
 
 def _blocks_for(variant: str, m: int, k: int, n: int, nbits: int,
                 interpret: bool):
-    """(bm, bn, bk) for one wrapper call: the autotuner's cached winner for
-    this key, else exactly `_pick_blocks` (DESIGN.md §11). Called while the
+    """(bm, bn, bk, rows) for one wrapper call: the autotuner's cached winner
+    for this key, else exactly `_pick_blocks` (DESIGN.md §11), and the rows
+    each decoded weight tile serves (`_rows_per_decode`). Called while the
     wrapper's jit traces, so it never measures."""
     blocks = autotune.pick_blocks(m, k, n, nbits=nbits, variant=variant,
                                   interpret=interpret)
-    _log_blocks(variant, m, k, n, blocks)
-    return blocks
+    rows = _rows_per_decode(variant, m, blocks, nbits)
+    _log_blocks(variant, m, k, n, blocks, rows)
+    return (*blocks, rows)
+
+
+def _rows_per_decode(variant: str, m: int, blocks, nbits: int,
+                     n_ops: int = 1) -> int:
+    """The rows that share one decoded weight tile: the fused GEMMs' M chunk
+    (`autotune.m_chunk` of M padded to bm), which the wrappers pass to the
+    kernel as `m_chunk`; one M block elsewhere (a GEMV's whole M)."""
+    bm, bn, bk = blocks
+    if variant in ("lut_fused", "lut_fused_multi"):
+        return autotune.m_chunk(round_up(m, bm), bm, bn, bk, nbits, n_ops)
+    return bm
 
 
 def pad_for_kernel(x: jax.Array, packed: jax.Array, bm: int, bk: int, bn: int,
@@ -92,7 +105,7 @@ def lut_gemm(
     if kc != k:  # group padding: packed codes carry zero-code tail rows
         x = jnp.pad(x, ((0, 0), (0, kc - k)))
         k = kc
-    bm, bn, bk = _blocks_for("lut_f32", m, k, n, nbits, interpret)
+    bm, bn, bk, _ = _blocks_for("lut_f32", m, k, n, nbits, interpret)
     xp, cp, (m0, n0) = pad_for_kernel(x, packed_codes, bm, bk, bn, nbits)
     y = lut_matmul_f32(xp, cp, cb, bm=bm, bn=bn, bk=bk, interpret=interpret,
                        nbits=nbits)
@@ -116,7 +129,7 @@ def lut_gemm_int8(
     if kc != k:
         q = jnp.pad(q, ((0, 0), (0, kc - k)))
         k = kc
-    bm, bn, bk = _blocks_for("lut_int8", m, k, n, nbits, interpret)
+    bm, bn, bk, _ = _blocks_for("lut_int8", m, k, n, nbits, interpret)
     qp, cp, (m0, n0) = pad_for_kernel(q, packed_codes, bm, bk, bn, nbits)
     y = lut_matmul_int8(qp, cp, cb, act_scale, bm=bm, bn=bn, bk=bk,
                         interpret=interpret, nbits=nbits)
@@ -150,7 +163,7 @@ def lut_gemm_fused(
         inv_scale = jnp.pad(inv_scale, (0, kc - k))
         k = kc
     variant = "lut_fused_gemv" if m < 128 else "lut_fused"
-    bm, bn, bk = _blocks_for(variant, m, k, n, nbits, interpret)
+    bm, bn, bk, rows = _blocks_for(variant, m, k, n, nbits, interpret)
     xp, cp, (m0, n0) = pad_for_kernel(x, packed_codes, bm, bk, bn, nbits)
     invp = jnp.pad(inv_scale.astype(jnp.float32), (0, xp.shape[1] - k))
     if m < 128:
@@ -159,8 +172,8 @@ def lut_gemm_fused(
                                   interpret=interpret, nbits=nbits)
     else:
         y = lut_matmul_fused(xp, invp, cp, cb, quantize=quantize,
-                             bm=bm, bn=bn, bk=bk, interpret=interpret,
-                             nbits=nbits)
+                             bm=bm, bn=bn, bk=bk, m_chunk=rows,
+                             interpret=interpret, nbits=nbits)
     y = y[:m0, :n0]
     return y * act_scale if quantize else y
 
@@ -170,9 +183,10 @@ def lut_gemm_fused(
 # ---------------------------------------------------------------------------
 
 def _multi_blocks(m: int, k: int, widths, nbits, interpret: bool):
-    """(bm, bn, bk) for a fused multi call, or None when the projections'
-    heuristic bn choices disagree (the wrapper then falls back to unfused
-    calls so fused-vs-unfused bit-equality never depends on a re-tiling).
+    """(bm, bn, bk, rows) for a fused multi call (rows as in `_blocks_for`),
+    or None when the projections' heuristic bn choices disagree (the
+    wrapper then falls back to unfused calls so fused-vs-unfused
+    bit-equality never depends on a re-tiling).
 
     Under the interpreter the per-projection heuristic is used directly —
     the SAME (bm, bk) every unfused call gets (they depend only on m and k)
@@ -193,8 +207,9 @@ def _multi_blocks(m: int, k: int, widths, nbits, interpret: bool):
         key = autotune.normalize_key(m, k, sum(widths), max(nbits), variant,
                                      jax.default_backend(), len(widths))
         blocks = autotune.get_cache().get(key) or blocks
-    _log_blocks(variant, m, k, sum(widths), blocks)
-    return blocks
+    rows = _rows_per_decode(variant, m, blocks, max(nbits), len(widths))
+    _log_blocks(variant, m, k, sum(widths), blocks, rows)
+    return (*blocks, rows)
 
 
 @functools.partial(
@@ -222,7 +237,7 @@ def lut_gemm_fused_multi(
     if blocks is None:
         raise ValueError("lut_gemm_fused_multi: projections disagree on bn; "
                          "caller must fall back to unfused calls")
-    bm, bn, bk = blocks
+    bm, bn, bk, rows = blocks
     # shared K padding: bk is a multiple of every packing group size, so
     # round_up(k, bk) covers each projection's group padding exactly as the
     # unfused wrapper's padded_d_in -> pad_for_kernel chain does
@@ -242,7 +257,8 @@ def lut_gemm_fused_multi(
         y = lut_matmul_fused_multi_gemv(x, inv, cb_stack, *padded,
                                         bm=mp, **kw)
     else:
-        y = lut_matmul_fused_multi(x, inv, cb_stack, *padded, bm=bm, **kw)
+        y = lut_matmul_fused_multi(x, inv, cb_stack, *padded, bm=bm,
+                                   m_chunk=rows, **kw)
     outs, off = [], 0
     for p, (wp, n0) in enumerate(zip(wps, n_true)):
         seg = y[:m, off:off + n0]
@@ -324,14 +340,17 @@ def _resolve_mode(use_kernel: Optional[bool]) -> str:
 # traced step IS the per-step launch count.
 class LaunchLog(list):
     """The tags of the LUT launches traced in a `track_lut_launches`
-    context, plus `blocks`: {(variant, m, k, n): (bm, bn, bk)} for every
-    kernel problem the wrappers resolved there. The jitted wrappers resolve
-    blocks only when they first trace a shape, so open the context before
-    the process's first trace of the step to see them all."""
+    context, plus, for every kernel problem the wrappers resolved there,
+    keyed (variant, m, k, n): `blocks`, its (bm, bn, bk), and
+    `rows_per_decode`, the rows each decoded weight tile serves — the fused
+    GEMMs' M chunk, one M block elsewhere (a GEMV's whole M). The jitted
+    wrappers resolve blocks only when they first trace a shape, so open the
+    context before the process's first trace of the step to see them all."""
 
     def __init__(self):
         super().__init__()
         self.blocks: Dict[Tuple[str, int, int, int], Tuple[int, ...]] = {}
+        self.rows_per_decode: Dict[Tuple[str, int, int, int], int] = {}
 
 
 _LAUNCH_LOG: Optional[LaunchLog] = None
@@ -354,9 +373,12 @@ def _log_launch(tag: str) -> None:
         _LAUNCH_LOG.append(tag)
 
 
-def _log_blocks(variant: str, m: int, k: int, n: int, blocks) -> None:
-    if _LAUNCH_LOG is not None:
-        _LAUNCH_LOG.blocks[(variant, m, k, n)] = tuple(blocks)
+def _log_blocks(variant: str, m: int, k: int, n: int, blocks,
+                rows: int) -> None:
+    if _LAUNCH_LOG is None:
+        return
+    _LAUNCH_LOG.blocks[(variant, m, k, n)] = tuple(blocks)
+    _LAUNCH_LOG.rows_per_decode[(variant, m, k, n)] = rows
 
 
 # ---------------------------------------------------------------------------

@@ -13,8 +13,8 @@ import pytest
 from repro.kernels import autotune, ops
 from repro.kernels.autotune import (AutotuneCache, VMEM_BUDGET,
                                     candidate_blocks, flash_heuristic,
-                                    heuristic_blocks, normalize_key,
-                                    paged_heuristic,
+                                    heuristic_blocks, m_chunk,
+                                    normalize_key, paged_heuristic,
                                     pick_blocks, pick_flash_blocks,
                                     pick_paged_pad, vmem_bytes)
 
@@ -89,6 +89,58 @@ class TestCandidateGrid:
     def test_flash_heuristic_clamps_to_geometry(self):
         assert flash_heuristic(512, 1024) == (256, 512)
         assert flash_heuristic(64, 128) == (64, 128)
+
+
+# the wide (prefill_chunk) step's LUT GEMM launches of both benchmark cells:
+# 16 slots x 128 (qwen2-1.5b, 4-bit) and 32 slots x 64 (starcoder2-15b,
+# 2-bit) rows; (k, projection widths, nbits)
+WIDE_M = 2048
+WIDE_LAUNCHES = {
+    "qwen2.qkv": (1536, (2048, 256, 256), 4),
+    "qwen2.gate_up": (1536, (8960, 8960), 4),
+    "qwen2.wo": (2048, (1536,), 4),
+    "qwen2.w_down": (8960, (1536,), 4),
+    "starcoder2.qkv": (6144, (6144, 512, 512), 2),
+    "starcoder2.wo": (6144, (6144,), 2),
+    "starcoder2.w_up": (6144, (24576,), 2),
+    "starcoder2.w_down": (24576, (6144,), 2),
+}
+
+
+class TestMChunk:
+    @pytest.mark.parametrize("launch", sorted(WIDE_LAUNCHES))
+    def test_cells_wide_launches_decode_once(self, launch):
+        """Both cells' 2048-row launches fit one chunk: each weight tile is
+        decoded once per launch, within the VMEM budget."""
+        k, widths, nbits = WIDE_LAUNCHES[launch]
+        bm, bn, bk = heuristic_blocks(WIDE_M, k, widths[0])
+        rows = m_chunk(WIDE_M, bm, bn, bk, nbits, len(widths))
+        assert rows == WIDE_M
+        assert vmem_bytes(bm, bn, bk, nbits, len(widths),
+                          rows=rows) <= VMEM_BUDGET
+
+    @pytest.mark.parametrize("m", [4096, 8192, 16384])
+    def test_chunk_shrinks_past_the_budget(self, m):
+        bm, bn, bk = heuristic_blocks(m, 1536, 2048)
+        rows = m_chunk(m, bm, bn, bk, 4, 3)
+        assert bm <= rows < m and m % rows == 0 and rows % bm == 0
+        assert vmem_bytes(bm, bn, bk, 4, 3, rows=rows) <= VMEM_BUDGET
+        assert vmem_bytes(bm, bn, bk, 4, 3, rows=2 * rows) > VMEM_BUDGET
+
+    def test_chunk_divides_m_and_never_drops_under_one_block(self):
+        # 19 blocks (a prime count over the budget): only 1 or 19 divide
+        assert m_chunk(19 * 128, 128, 256, 512) == 128
+        # 24 blocks at a width whose budget holds 18: the largest divisor
+        assert m_chunk(24 * 128, 128, 256, 512) == 12 * 128
+        # one M block, and a block too wide for any chunk: bm itself
+        assert m_chunk(128, 128, 256, 512) == 128
+        assert m_chunk(1024, 128, 8192, 512) == 128
+
+    def test_gemm_candidates_run_at_one_block(self):
+        # every GEMM candidate fits at one M block, the least chunk
+        for bm, bn, bk in candidate_blocks(2048, 6144, 24576, 2)[1:]:
+            assert vmem_bytes(bm, bn, bk, 2, rows=bm) <= VMEM_BUDGET
+            assert m_chunk(2048, bm, bn, bk, 2) >= bm
 
 
 class TestInterpretFallback:
@@ -231,6 +283,21 @@ class TestPersistentCache:
             # and the empty cache still resolves deterministically
             assert pick_blocks(8, 4096, 4096, interpret=True, cache=c) == \
                 heuristic_blocks(8, 4096, 4096)
+
+    def test_schema_bump_discards_old_entries(self, tmp_path):
+        """Winners stored under the per-M-block GEMM layout (schema v2) are
+        not served: the file reads as empty and the heuristic resolves."""
+        assert autotune.CACHE_SCHEMA_VERSION > 2
+        path = tmp_path / "autotune.json"
+        key = normalize_key(2048, 1536, 1536, 4, "lut_fused", "tpu")
+        path.write_text(json.dumps({"version": 2, "entries": {
+            key: {"blocks": [256, 512, 1024], "us": 1.0,
+                  "source": "measured"}}}))
+        c = AutotuneCache(str(path))
+        assert c.entries == {}
+        assert c.get(key) is None
+        c.put(key, (128, 256, 512), 1.0)
+        assert AutotuneCache(str(path)).get(key) == (128, 256, 512)
 
     def test_save_is_versioned_and_sorted(self, tmp_path):
         path = str(tmp_path / "sub" / "autotune.json")

@@ -14,12 +14,18 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.lut import packed_rows
-from repro.kernels.ops import lut_gemm_fused, lut_gemm_fused_multi
+from repro.kernels.ops import (lut_gemm_fused, lut_gemm_fused_multi,
+                               track_lut_launches)
 from repro.kernels.paged_attention import paged_pool_attention
 
 D, Q, KV_FLAT, FF = 1536, 2048, 256, 8960
 SLOTS = 8                                   # decode GEMV rows (sublane-padded)
 BLOCK, NUM_BLOCKS, TABLE, KV, HD, HEADS = 16, 128, 32, 2, 128, 16
+# the wide (prefill_chunk) step's rows in both benchmark cells: 16 slots x
+# 128 (qwen2-1.5b, 4-bit) and 32 slots x 64 (starcoder2-15b at its
+# published widths, 2-bit: d 6144, 48 heads x 128, 4 kv heads, d_ff 24576)
+WIDE = 2048
+SC_D, SC_KV_FLAT, SC_FF = 6144, 512, 24576
 
 
 @pytest.fixture(scope="module")
@@ -80,6 +86,52 @@ def test_lut_multi_gemv_compiles(one_chip, nbits, widths):
         *[_spec(one_chip, (packed_rows(D, nbits), w), jnp.uint8)
           for w in widths])
     assert "tpu_custom_call" in hlo
+
+
+def _gemm_compiles(dev, nbits, k, widths):
+    """Compile one wide fused LUT launch (multi when several widths) and
+    return the launch log's rows per decoded tile."""
+    p = len(widths)
+    with track_lut_launches() as log:
+        if p == 1:
+            hlo = _compile(
+                lambda x, inv, pk, cb, act: lut_gemm_fused(
+                    x, inv, pk, cb, act, quantize=True, interpret=False,
+                    nbits=nbits),
+                _spec(dev, (WIDE, k), jnp.bfloat16),
+                _spec(dev, (k,), jnp.float32),
+                _spec(dev, (packed_rows(k, nbits), widths[0]), jnp.uint8),
+                _spec(dev, (16,), jnp.float32),
+                _spec(dev, (), jnp.float32))
+        else:
+            hlo = _compile(
+                lambda x, inv, cb, act, *pk: lut_gemm_fused_multi(
+                    x, inv, cb, act, *pk, quantize=(True,) * p,
+                    interpret=False, nbits=(nbits,) * p),
+                _spec(dev, (WIDE, k), jnp.bfloat16),
+                _spec(dev, (p, k), jnp.float32),
+                _spec(dev, (p, 16), jnp.float32),
+                _spec(dev, (p,), jnp.float32),
+                *[_spec(dev, (packed_rows(k, nbits), w), jnp.uint8)
+                  for w in widths])
+    assert "tpu_custom_call" in hlo
+    return log.rows_per_decode
+
+
+@pytest.mark.parametrize("nbits,k,widths", [
+    (4, D, (Q, KV_FLAT, KV_FLAT)), (4, D, (FF, FF)), (4, Q, (D,)),
+    (4, FF, (D,)),
+    (2, SC_D, (SC_D, SC_KV_FLAT, SC_KV_FLAT)), (2, SC_D, (SC_D,)),
+    (2, SC_D, (SC_FF,)), (2, SC_FF, (SC_D,))],
+    ids=["qwen2.qkv", "qwen2.gate_up", "qwen2.wo", "qwen2.w_down",
+         "starcoder2.qkv", "starcoder2.wo", "starcoder2.w_up",
+         "starcoder2.w_down"])
+def test_lut_wide_gemm_compiles_and_decodes_once(one_chip, nbits, k, widths):
+    """The wide step's LUT GEMMs fit the chip's scoped VMEM with all 2048
+    rows sharing each decoded tile, as the launch log records."""
+    rows = _gemm_compiles(one_chip, nbits, k, widths)
+    variant = "lut_fused" if len(widths) == 1 else "lut_fused_multi"
+    assert rows == {(variant, WIDE, k, sum(widths)): WIDE}
 
 
 @pytest.mark.parametrize("pool", ["float", "int8"])

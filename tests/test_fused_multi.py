@@ -12,13 +12,15 @@ the bounded-trace contract intact.
 import dataclasses
 
 import jax
+import jax.extend as jex
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core.api import compress_model, dense_to_clustered
+from repro.kernels.lut_matmul import lut_matmul_fused_multi
 from repro.kernels.ops import (clustered_linear, clustered_linear_multi,
-                               lut_serving, track_lut_launches)
+                               lut_serving, pad_codebook, track_lut_launches)
 from repro.kernels.paged_attention import paged_pool_attention
 from repro.kernels.ref import (lut_matmul_fused_multi_ref,
                                paged_pool_attention_ref)
@@ -52,7 +54,7 @@ GROUPS = {
 class TestFusedMultiBitEquality:
     @pytest.mark.parametrize("group", sorted(GROUPS))
     @pytest.mark.parametrize("nbits", [2, 3, 4])
-    @pytest.mark.parametrize("m", [1, 7])
+    @pytest.mark.parametrize("m", [1, 7, 256])
     def test_uniform_width(self, group, nbits, m):
         k, widths = GROUPS[group]
         rng = np.random.default_rng(hash((group, nbits, m)) % 2**31)
@@ -80,6 +82,32 @@ class TestFusedMultiBitEquality:
             solo = tuple(clustered_linear(x, ct) for ct in cts)
         for f, s in zip(fused, solo):
             assert jnp.array_equal(f, s)
+
+    @pytest.mark.parametrize("nbits", [(2, 2, 2), (3, 3, 3), (4, 4, 4),
+                                       (4, 2, 3)],
+                             ids=["2bit", "3bit", "4bit", "mixed"])
+    @pytest.mark.parametrize("m,m_chunk", [(128, 128), (256, 256),
+                                           (1024, 1024), (1024, 256)],
+                             ids=["m128", "m256", "m1024", "m1024-chunk256"])
+    def test_gemm_rows_equal_one_block_launches(self, nbits, m, m_chunk):
+        """The multi GEMM decodes the live projection's tile once per M
+        chunk; every output row stays bit-equal to a launch of its 128 rows
+        alone (one M block, the per-M-block path), at uniform and mixed
+        per-projection widths. m_chunk=256 at M=1024 walks four chunks."""
+        k, widths = 256, (256, 128, 128)
+        rng = np.random.default_rng(m + sum(nbits))
+        cts = tuple(_ct(rng, k, n, nb, act_scale=0.03)
+                    for n, nb in zip(widths, nbits))
+        x = jnp.asarray(rng.normal(size=(m, k)).astype(np.float32))
+        inv = jnp.stack([ct.inv_scale for ct in cts])
+        cb = jnp.stack([pad_codebook(ct.codebook) for ct in cts])
+        kw = dict(widths=widths, quantize=(True, False, True), bm=128,
+                  bn=128, bk=128, interpret=True, nbits=nbits)
+        packed = [ct.packed for ct in cts]
+        y = lut_matmul_fused_multi(x, inv, cb, *packed, m_chunk=m_chunk, **kw)
+        rows = [lut_matmul_fused_multi(x[r:r + 128], inv, cb, *packed, **kw)
+                for r in range(0, m, 128)]
+        assert jnp.array_equal(y, jnp.concatenate(rows))
 
     def test_float_path_without_act_scale(self):
         """Uncalibrated tensors (act_scale=None) fuse through the float
@@ -124,6 +152,56 @@ class TestFusedMultiBitEquality:
         assert log == []          # ref mode never launches
         for f, s in zip(fused, solo):
             assert jnp.array_equal(f, s)
+
+
+def _pallas_grids(fn, aval):
+    """The grids of the pallas_calls `fn` traces to, in trace order."""
+    grids = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                grids.append(tuple(eqn.params["grid_mapping"].grid))
+            for v in eqn.params.values():
+                for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                    if isinstance(sub, jex.core.ClosedJaxpr):
+                        walk(sub.jaxpr)
+                    elif isinstance(sub, jex.core.Jaxpr):
+                        walk(sub)
+
+    walk(jax.make_jaxpr(fn)(aval).jaxpr)
+    return grids
+
+
+class TestLaunchRecord:
+    @pytest.mark.parametrize("m,rows", [(16, 16), (2048, 2048), (8192, 2048)],
+                             ids=["gemv", "gemm-one-chunk", "gemm-4-chunks"])
+    def test_rows_per_decode(self, m, rows):
+        """The launch log records the rows each decoded weight tile serves:
+        a GEMV's whole M (one decode per launch), a GEMM's M chunk — all
+        2048 rows of a wide step, 2048 of 8192 once M passes the VMEM
+        budget — and it is the chunk the traced kernels run: a GEMM's grid
+        (M/rows, N/bn, K/bk, rows/bm). Traced only, so no kernel runs."""
+        rng = np.random.default_rng(m)
+        k, widths = 768, (512, 256, 256)
+        cts = tuple(_ct(rng, k, n, 4, act_scale=0.03) for n in widths)
+        variant = "lut_fused_multi_gemv" if m < 128 else "lut_fused_multi"
+        aval = jax.ShapeDtypeStruct((m, k), jnp.float32)
+        with lut_serving("interpret"), track_lut_launches() as log:
+            grids = (_pallas_grids(lambda x: clustered_linear_multi(x, cts),
+                                   aval)
+                     + _pallas_grids(lambda x: clustered_linear(x, cts[0]),
+                                     aval))
+        assert log.rows_per_decode == {
+            (variant, m, k, sum(widths)): rows,
+            (variant.replace("_multi", ""), m, k, widths[0]): rows}
+        assert len(grids) == 2
+        for grid in grids:
+            if m < 128:
+                assert len(grid) == 2       # GEMV: (N/bn, K/bk)
+            else:
+                bm = log.blocks[(variant, m, k, sum(widths))][0]
+                assert (grid[0], grid[-1]) == (m // rows, rows // bm)
 
 
 class TestPoolAttentionKernel:

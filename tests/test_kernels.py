@@ -252,6 +252,25 @@ class TestBitWidths:
         np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                    rtol=1e-5, atol=1e-4)
 
+    @pytest.mark.parametrize("nbits", [2, 3, 4])
+    @pytest.mark.parametrize("m,m_chunk", [(128, 128), (256, 256),
+                                           (1024, 1024), (1024, 256)],
+                             ids=["m128", "m256", "m1024", "m1024-chunk256"])
+    def test_gemm_rows_equal_one_block_launches(self, nbits, m, m_chunk):
+        """The GEMM decodes each weight tile once per M chunk and shares it
+        across the chunk's 128-row M blocks; every output row must stay
+        bit-equal to a launch of its 128 rows alone (one M block: one decode
+        per block, the per-M-block path). m_chunk=256 at M=1024 walks four
+        chunks on the outer M axis."""
+        k, n = 256, 256            # two K steps, two N blocks at bk=bn=128
+        x, packed, cb, inv, _ = self._mk(nbits, m, k, n, seed=m + nbits)
+        kw = dict(quantize=True, bm=128, bn=128, bk=128, interpret=True,
+                  nbits=nbits)
+        y = lut_matmul_fused(x, inv, packed, cb, m_chunk=m_chunk, **kw)
+        rows = [lut_matmul_fused(x[r:r + 128], inv, packed, cb, **kw)
+                for r in range(0, m, 128)]
+        assert jnp.array_equal(y, jnp.concatenate(rows))
+
     def test_two_bit_packed_tile_is_half_the_bytes(self):
         """The §10 stream contract at the kernel boundary: the packed operand
         a 2-bit call streams is exactly half the int4 one's bytes."""
